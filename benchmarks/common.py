@@ -17,7 +17,10 @@ import time
 
 from repro.core.perf_model import opt_perf_model
 from repro.core.router import make_baseline_cluster, make_slos_serve_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.telemetry import MetricsRegistry, prometheus_text
+
+enable_compile_cache()
 
 PERF = opt_perf_model(7e9)
 PERF_SPEC = opt_perf_model(7e9, spec=True)

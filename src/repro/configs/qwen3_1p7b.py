@@ -1,4 +1,5 @@
-"""qwen3-1.7b [hf:Qwen/Qwen3-8B family] — dense GQA with qk_norm.
+"""qwen3-1.7b [hf:Qwen/Qwen3-1.7B] — dense GQA with qk_norm and tied
+input/output embeddings, as published.
 
 ``qwen3-1.7b-swa`` is the beyond-paper sliding-window variant that makes
 the long_500k decode shape sub-quadratic (see DESIGN.md §long_500k).
@@ -11,7 +12,7 @@ CONFIG = ModelConfig(
     name="qwen3-1.7b", arch_type="dense",
     n_layers=28, d_model=2048, n_heads=16, n_kv_heads=8, d_ff=6144,
     vocab=151936, head_dim=128, qk_norm=True, rope_theta=1000000.0,
-    source="hf:Qwen/Qwen3-8B")
+    tie_embeddings=True, source="hf:Qwen/Qwen3-1.7B")
 
 SWA = dataclasses.replace(CONFIG, name="qwen3-1.7b-swa",
                           sliding_window=4096)
@@ -19,8 +20,8 @@ SWA = dataclasses.replace(CONFIG, name="qwen3-1.7b-swa",
 REDUCED = ModelConfig(
     name="qwen3-1.7b-reduced", arch_type="dense",
     n_layers=2, d_model=256, n_heads=4, n_kv_heads=2, d_ff=512,
-    vocab=512, head_dim=64, qk_norm=True,
-    source="hf:Qwen/Qwen3-8B")
+    vocab=512, head_dim=64, qk_norm=True, tie_embeddings=True,
+    source="hf:Qwen/Qwen3-1.7B")
 
 REDUCED_SWA = dataclasses.replace(REDUCED, name="qwen3-1.7b-swa-reduced",
                                   sliding_window=64)

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle GQA expansion, padding to tile multiples, layout moves, and the
-interpret-mode switch (CPU containers execute the kernel bodies in Python;
-on TPU the same calls compile to Mosaic).
+interpret-mode switch: ``interpret=None`` interprets the kernel bodies
+only where the backend is the CPU; on TPU the same calls compile to
+Mosaic.  The raw kernel entry points compile unless a caller asks for
+``interpret=True``.
 """
 from __future__ import annotations
 
@@ -62,43 +64,45 @@ def attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret"))
-def paged_attention(q, k_pages, v_pages, block_table, seq_lens, *,
-                    scale: float = None, window: int = None,
+def paged_attention(q, k_pages, v_pages, block_table, seq_lens, layer=None,
+                    *, scale: float = None, window: int = None,
                     interpret: bool = None):
     """Decode attention against paged KV.  q: (B,H,hd); k/v_pages:
-    (n_pages, page, KVH, hd); block_table: (B,max_pages); seq_lens: (B,);
-    window: sliding-window size in tokens (None = full causal)."""
+    (n_pages, page, KVH, hd), or a per-layer stack read at ``layer``;
+    block_table: (B,max_pages); seq_lens: (B,); window: sliding-window
+    size in tokens (None = full causal)."""
     interpret = _interpret_default() if interpret is None else interpret
     return paged_decode_attention(q, k_pages, v_pages,
                                   block_table.astype(jnp.int32),
                                   seq_lens.astype(jnp.int32),
-                                  scale=scale, window=window,
+                                  scale=scale, window=window, layer=layer,
                                   interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret"))
 def paged_prefill(q, k_new, v_new, k_pages, v_pages, block_table, pos0,
-                  chunk_len, *, scale: float = None, window: int = None,
-                  interpret: bool = None):
+                  chunk_len, layer=None, *, scale: float = None,
+                  window: int = None, interpret: bool = None):
     """Fused chunked-prefill attention: writes the chunk's K/V into pool
     pages in-kernel and attends over each lane's paged history in the
     same pass.  q: (B,S,H,hd); k_new/v_new: (B,S,KVH,hd); k/v_pages:
-    (n_pages,page,KVH,hd); block_table: (B,max_pages); pos0/chunk_len:
-    (B,).  Returns (out, k_pages', v_pages'); the pool buffers update in
-    place via the kernel's input→output aliasing."""
+    (n_pages,page,KVH,hd) or a per-layer stack updated at ``layer``;
+    block_table: (B,max_pages); pos0/chunk_len: (B,).  Returns (out,
+    k_pages', v_pages'); the pool buffers update in place via the
+    kernel's input→output aliasing."""
     interpret = _interpret_default() if interpret is None else interpret
     return paged_prefill_attention(q, k_new, v_new, k_pages, v_pages,
                                    block_table.astype(jnp.int32),
                                    pos0.astype(jnp.int32),
                                    chunk_len.astype(jnp.int32),
-                                   scale=scale, window=window,
+                                   scale=scale, window=window, layer=layer,
                                    interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret"))
 def paged_verify(q, k_new, v_new, k_pages, v_pages, block_table, pos0,
-                 chunk_len, *, scale: float = None, window: int = None,
-                 interpret: bool = None):
+                 chunk_len, layer=None, *, scale: float = None,
+                 window: int = None, interpret: bool = None):
     """Fused speculative-verify attention: scores the sl+1 verify window
     ([last emitted] + drafts) as an in-kernel chunk over the paged
     history — one device op replacing 2 page scatters + a slab attention.
@@ -108,14 +112,14 @@ def paged_verify(q, k_new, v_new, k_pages, v_pages, block_table, pos0,
                                   block_table.astype(jnp.int32),
                                   pos0.astype(jnp.int32),
                                   chunk_len.astype(jnp.int32),
-                                  scale=scale, window=window,
+                                  scale=scale, window=window, layer=layer,
                                   interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
 def mla_paged_prefill(q_lat, q_rope, ckv_new, krope_new, ckv_pages,
-                      krope_pages, block_table, pos0, chunk_len, *,
-                      scale: float, interpret: bool = None):
+                      krope_pages, block_table, pos0, chunk_len,
+                      layer=None, *, scale: float, interpret: bool = None):
     """Fused MLA chunked prefill: writes the chunk's ckv/krope latents
     into pool pages in-kernel and attends over the paged latent history
     in the same (absorbed, latent-space) pass.  q_lat: (B,S,H,r) =
@@ -126,7 +130,8 @@ def mla_paged_prefill(q_lat, q_rope, ckv_new, krope_new, ckv_pages,
     return mla_paged_prefill_attention(
         q_lat, q_rope, ckv_new, krope_new, ckv_pages, krope_pages,
         block_table.astype(jnp.int32), pos0.astype(jnp.int32),
-        chunk_len.astype(jnp.int32), scale=scale, interpret=interpret)
+        chunk_len.astype(jnp.int32), scale=scale, layer=layer,
+        interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -76,41 +76,48 @@ def _paged_verify_impl() -> str:
 
 # ----------------------------- paged KV --------------------------------- #
 def paged_write(pages, vals, block_table, pos0, chunk_len,
-                op_key: str = "paged_write"):
+                op_key: str = "paged_write", layer=None):
     """Scatter per-token vectors of a chunk into KV pages.
 
-    pages: (P, page, ...); vals: (B, S, ...); block_table: (B, max_pages);
-    pos0 / chunk_len: (B,) int32.  Token i of lane b lands at global
-    position pos0[b]+i inside the lane's block table; positions at or past
-    chunk_len[b] (padding / inactive lanes) are dropped, so one call can
-    serve bucketed prefill chunks and masked decode lanes alike.
-    ``op_key`` picks the OP_STATS counter (verify audits separately).
+    pages: (P, page, ...), or a (layers, P, page, ...) stack written at
+    index ``layer`` (the scatter addresses the stack directly, so a pool
+    carried through the layer scan updates in place); vals: (B, S, ...);
+    block_table: (B, max_pages); pos0 / chunk_len: (B,) int32.  Token i
+    of lane b lands at global position pos0[b]+i inside the lane's block
+    table; positions at or past chunk_len[b] (padding / inactive lanes)
+    are dropped, so one call can serve bucketed prefill chunks and masked
+    decode lanes alike.  ``op_key`` picks the OP_STATS counter (verify
+    audits separately).
     """
     OP_STATS[op_key] += 1
-    P, page = pages.shape[:2]
+    lead = 0 if layer is None else 1
+    P, page = pages.shape[lead:lead + 2]
     B, S = vals.shape[:2]
-    tail = pages.shape[2:]
+    tail = pages.shape[lead + 2:]
+    rows = (1 if layer is None else pages.shape[0]) * P * page
     pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]   # (B,S)
     slot = jnp.clip(pos // page, 0, block_table.shape[1] - 1)
     pid = jnp.take_along_axis(block_table, slot, axis=1)
     flat = pid * page + pos % page
+    if layer is not None:
+        flat = flat + layer * (P * page)
     valid = jnp.arange(S)[None, :] < chunk_len[:, None]
-    flat = jnp.where(valid, flat, P * page)          # OOB index -> dropped
-    out = pages.reshape((P * page,) + tail).at[flat.reshape(-1)].set(
+    flat = jnp.where(valid, flat, rows)              # OOB index -> dropped
+    out = pages.reshape((rows,) + tail).at[flat.reshape(-1)].set(
         vals.astype(pages.dtype).reshape((B * S,) + tail), mode="drop")
     return out.reshape(pages.shape)
 
 
-def paged_gather(pages, block_table):
+def paged_gather(pages, block_table, layer=None):
     """Materialize each lane's logical KV stream from its pages.
-    pages: (P, page, ...), block_table: (B, max_pages)
-    -> (B, max_pages*page, ...)."""
-    g = pages[block_table]                     # (B, max_pages, page, ...)
+    pages: (P, page, ...) or a (layers, P, page, ...) stack read at
+    ``layer``; block_table: (B, max_pages) -> (B, max_pages*page, ...)."""
+    g = pages[block_table] if layer is None else pages[layer, block_table]
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, kv_len, *,
-                           window=None, scale=None):
+                           window=None, scale=None, layer=None):
     """Single-token decode attention against paged KV — the backend
     dispatch point.  q: (B, 1, H, hd) -> (B, 1, H, hd)."""
     impl = PAGED_DECODE_IMPL
@@ -119,11 +126,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, kv_len, *,
     if impl == "pallas":
         from repro.kernels import ops
         out = ops.paged_attention(q[:, 0], k_pages, v_pages,
-                                  block_table, kv_len, scale=scale,
+                                  block_table, kv_len, layer, scale=scale,
                                   window=window)
         return out[:, None].astype(q.dtype)
-    k = paged_gather(k_pages, block_table).astype(q.dtype)
-    v = paged_gather(v_pages, block_table).astype(q.dtype)
+    k = paged_gather(k_pages, block_table, layer).astype(q.dtype)
+    v = paged_gather(v_pages, block_table, layer).astype(q.dtype)
     B = q.shape[0]
     mask = causal_mask(B, 1, k.shape[1], kv_len - 1, kv_len, window)
     return sdpa(q, k, v, mask, scale)
@@ -244,11 +251,13 @@ def sdpa_chunked(q, k, v, *, pos0, kv_len, window=None, causal=True,
 def attn_forward(p, x, cfg: ModelConfig, *, positions, cache=None,
                  pos0=None, layer_window: Optional[int] = None,
                  causal: bool = True, block_tables=None, chunk_len=None,
-                 verify: bool = False):
+                 verify: bool = False, layer=None):
     """Returns (out, new_cache).
 
     cache: None (full-causal, no cache kept), dict(k, v) fixed buffers, or
-    dict(k_pages, v_pages) page pools addressed via ``block_tables``.
+    dict(k_pages, v_pages) page pools addressed via ``block_tables`` —
+    per-layer stacks addressed at ``layer`` when the layer scan carries
+    them.
     pos0: (B,) write offsets into the cache (chunked prefill / decode).
     chunk_len: (B,) true (unpadded) chunk lengths for paged writes.
     causal=False: bidirectional (encoder) attention, no cache.
@@ -304,26 +313,27 @@ def attn_forward(p, x, cfg: ModelConfig, *, positions, cache=None,
                 OP_STATS["fused_verify"] += 1
                 out, kp, vp = ops.paged_verify(
                     q, k, v, cache["k_pages"], cache["v_pages"],
-                    block_tables, pos0, chunk_len, window=window)
+                    block_tables, pos0, chunk_len, layer, window=window)
             else:
                 OP_STATS["fused_prefill"] += 1
                 out, kp, vp = ops.paged_prefill(
                     q, k, v, cache["k_pages"], cache["v_pages"],
-                    block_tables, pos0, chunk_len, window=window)
+                    block_tables, pos0, chunk_len, layer, window=window)
             return out.astype(q.dtype), {"k_pages": kp, "v_pages": vp}
         wkey = "verify_write" if verify and Sq > 1 else "paged_write"
         kp = paged_write(cache["k_pages"], k, block_tables, pos0, chunk_len,
-                         op_key=wkey)
+                         op_key=wkey, layer=layer)
         vp = paged_write(cache["v_pages"], v, block_tables, pos0, chunk_len,
-                         op_key=wkey)
+                         op_key=wkey, layer=layer)
         new_cache = {"k_pages": kp, "v_pages": vp}
         kv_len = pos0 + Sq
         if Sq == 1:
             return paged_decode_attention(q, kp, vp, block_tables, kv_len,
-                                          window=window), new_cache
+                                          window=window,
+                                          layer=layer), new_cache
         OP_STATS["verify_attn" if verify else "prefill_attn"] += 1
-        ck = paged_gather(kp, block_tables).astype(q.dtype)
-        cv = paged_gather(vp, block_tables).astype(q.dtype)
+        ck = paged_gather(kp, block_tables, layer).astype(q.dtype)
+        cv = paged_gather(vp, block_tables, layer).astype(q.dtype)
         mask = causal_mask(B, Sq, ck.shape[1], pos0, kv_len, window)
         return sdpa(q, ck, cv, mask), new_cache
 
@@ -419,15 +429,25 @@ def init_mla(key, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
+def krope_pool_width(cfg: ModelConfig) -> int:
+    """Lane width of the paged rope-key pool: ``qk_rope_head_dim`` padded
+    to a multiple of 128.  A TPU lays a 64-wide row out in a 128-lane
+    tile anyway, and the fused MLA kernel can only DMA whole tiles, so the
+    pad costs no device memory; the pad lanes hold zeros."""
+    return -(-cfg.mla.qk_rope_head_dim // 128) * 128
+
+
 def mla_forward(p, x, cfg: ModelConfig, *, positions, cache=None, pos0=None,
-                block_tables=None, chunk_len=None, shard=None):
+                block_tables=None, chunk_len=None, shard=None, layer=None):
     """MLA: cache the compressed c_kv (kv_lora_rank) + shared rope key.
 
     Cache layout: {"ckv": (B,S,r), "krope": (B,S,rope_hd)} — this is the
     paper-exact compressed cache (DeepSeek-V2 §2.1), 9x smaller than GQA.
-    Paged layout: {"ckv_pages": (P,page,r), "krope_pages": (P,page,rope_hd)}
-    addressed via ``block_tables`` (the latent stream is paged exactly like
-    GQA KV, just with vector-valued tokens).
+    Paged layout: {"ckv_pages": (P,page,r), "krope_pages": (P,page,w)},
+    w = ``krope_pool_width(cfg)`` (rope_hd lane-padded), addressed via
+    ``block_tables`` (the latent stream is paged exactly like GQA KV, just
+    with vector-valued tokens); per-layer stacks addressed at ``layer``
+    when the layer scan carries them.
 
     Paged multi-token chunks dispatch per PAGED_PREFILL_IMPL: "fused" runs
     the latent-space Pallas kernel (kernels/paged_prefill.py) that writes
@@ -470,7 +490,7 @@ def mla_forward(p, x, cfg: ModelConfig, *, positions, cache=None, pos0=None,
             q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, p["w_uk"])
             ctx_lat, cc, ck = ops.mla_paged_prefill(
                 q_lat, q_rope, ckv, krope, cache["ckv_pages"],
-                cache["krope_pages"], block_tables, pos0, chunk_len,
+                cache["krope_pages"], block_tables, pos0, chunk_len, layer,
                 scale=(nope + rope_hd) ** -0.5)
             ctx = jnp.einsum("bqhr,rhv->bqhv", ctx_lat.astype(x.dtype),
                              p["w_uv"])
@@ -480,15 +500,18 @@ def mla_forward(p, x, cfg: ModelConfig, *, positions, cache=None, pos0=None,
             out = jnp.einsum("bshk,hkd->bsd", ctx, p["wo"])
             return out, {"ckv_pages": cc, "krope_pages": ck}
         cc = paged_write(cache["ckv_pages"], ckv, block_tables, pos0,
-                         chunk_len)
-        ck = paged_write(cache["krope_pages"], krope, block_tables, pos0,
-                         chunk_len)
+                         chunk_len, layer=layer)
+        pad = cache["krope_pages"].shape[-1] - rope_hd
+        ck = paged_write(cache["krope_pages"],
+                         jnp.pad(krope, ((0, 0), (0, 0), (0, pad))),
+                         block_tables, pos0, chunk_len, layer=layer)
         if Sq > 1:
             OP_STATS["prefill_attn"] += 1
         kv_len = pos0 + Sq
         new_cache = {"ckv_pages": cc, "krope_pages": ck}
-        ckv_all = paged_gather(cc, block_tables).astype(x.dtype)
-        krope_all = paged_gather(ck, block_tables).astype(x.dtype)
+        ckv_all = paged_gather(cc, block_tables, layer).astype(x.dtype)
+        krope_all = paged_gather(ck, block_tables, layer)[..., :rope_hd]
+        krope_all = krope_all.astype(x.dtype)
         q_pos0 = pos0
     elif cache is not None:
         upd2 = jax.vmap(lambda buf, new, s: jax.lax.dynamic_update_slice(

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 
 from repro.models.attention import (attn_forward, attn_output,
                                     cross_attn_forward, init_attn, init_mla,
-                                    mla_forward, project_cross_kv)
+                                    krope_pool_width, mla_forward,
+                                    project_cross_kv)
 from repro.models.config import ModelConfig
 from repro.models.layers import (apply_mlp, apply_norm, embed, init_embed,
                                  init_mlp, init_norm, init_unembed)
@@ -59,13 +60,15 @@ def init_block(key, kind: str, cfg: ModelConfig, dtype=jnp.float32):
 def block_forward(p, kind: str, cfg: ModelConfig, x, *, positions,
                   cache=None, pos0=None, enc_kv=None, moe_cf=None,
                   block_tables=None, chunk_len=None, verify=False,
-                  shard=None):
+                  shard=None, layer=None):
     """Returns (x, new_cache, aux_loss).
 
     shard: serving ShardPlan when executing inside the engine's
     shard_map (distributed/sharding.py) — attention heads / MoE experts
     / dense-FFN hidden run shard-local, everything else replicated.
-    Cross-attention params stay replicated (shard is not forwarded)."""
+    Cross-attention params stay replicated (shard is not forwarded).
+    layer: index into per-layer page-pool stacks (paged segments whose
+    pools ride in the layer scan's carry); None = this layer's own cache."""
     if kind == "cross_attn":
         shard = None            # whole block replicates (serving specs)
     aux = jnp.zeros((), jnp.float32)
@@ -85,13 +88,15 @@ def block_forward(p, kind: str, cfg: ModelConfig, x, *, positions,
         y, new_self = mla_forward(p["attn"], h, cfg, positions=positions,
                                   cache=self_cache, pos0=pos0,
                                   block_tables=block_tables,
-                                  chunk_len=chunk_len, shard=shard)
+                                  chunk_len=chunk_len, shard=shard,
+                                  layer=layer)
     else:
         self_cache = cache.get("self") if cache else None
         ctx, new_self = attn_forward(p["attn"], h, cfg, positions=positions,
                                      cache=self_cache, pos0=pos0,
                                      block_tables=block_tables,
-                                     chunk_len=chunk_len, verify=verify)
+                                     chunk_len=chunk_len, verify=verify,
+                                     layer=layer)
         y = attn_output(p["attn"], ctx, shard=shard)
     x = x + y.astype(x.dtype)
     if kind == "cross_attn":
@@ -204,7 +209,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
         c = {"ckv_pages": jnp.zeros(
                  (n_pages, page_size, cfg.mla.kv_lora_rank), dtype),
              "krope_pages": jnp.zeros(
-                 (n_pages, page_size, cfg.mla.qk_rope_head_dim), dtype)}
+                 (n_pages, page_size, krope_pool_width(cfg)), dtype)}
         if n > 1:
             c = jax.tree.map(
                 lambda a: jnp.broadcast_to(a, (n,) + a.shape), c)
@@ -294,6 +299,26 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
             aux_total += aux
             if cache is not None:
                 new_caches.append(c_new)
+        elif _paged(seg_c):
+            # page pools ride in the carry as (n, P, page, ...) stacks and
+            # each layer updates its slice in place (kernel aliasing /
+            # direct scatter at the layer index); as scan xs -> ys they
+            # would be copied into a second full-size stack
+            def body(carry, p_l):
+                xx, c, li = carry
+                ekv = None
+                if kind == "cross_attn":
+                    ekv = project_cross_kv(p_l["cross"], enc_states)
+                xx, c, aux = block_forward(
+                    p_l, kind, cfg, xx, positions=positions, cache=c,
+                    pos0=pos0_arr, enc_kv=ekv, moe_cf=moe_cf,
+                    block_tables=block_tables, chunk_len=chunk_len,
+                    verify=verify, shard=shard, layer=li)
+                return (xx, c, li + 1), aux
+            (x, c_new, _), auxs = jax.lax.scan(
+                body, (x, seg_c, jnp.int32(0)), p)
+            new_caches.append(c_new)
+            aux_total += jnp.sum(auxs)
         else:
             def body(carry, layer):
                 xx = carry
@@ -319,6 +344,13 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
             aux_total += jnp.sum(auxs)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     return x, new_caches, aux_total
+
+
+def _paged(seg_cache) -> bool:
+    """A segment cache holding page pools (attention / MLA), as opposed
+    to dense buffers, SSM lane state or no cache."""
+    return (isinstance(seg_cache, dict) and "self" in seg_cache
+            and any(k.endswith("_pages") for k in seg_cache["self"]))
 
 
 def logits_fn(params, cfg: ModelConfig, hidden):

@@ -167,7 +167,9 @@ class ClusterFrontend:
         ``devices_per_replica=k`` instead carves ``jax.devices()`` into
         contiguous k-device slices and gives replica i slice ``i % n``
         (its own mesh over ``shard_axes``) — e.g. 2 replicas x 2 devices
-        on a forced 4-device host is the CI "2x2" leg.  Autoscaler-grown
+        on a forced 4-device host is the CI "2x2" leg.  ``k=1`` is the
+        one-chip-replica layout: no mesh, replica i's params, pools and
+        inputs are committed to ``jax.devices()[i % n]``.  Autoscaler-grown
         replicas reuse the slices round-robin."""
         budget = SharedPageBudget(total_pages)
         if replica_pages is None:
@@ -176,8 +178,10 @@ class ClusterFrontend:
             spec_alpha = 0.7
         if not isinstance(telemetry, ClusterTelemetry):
             telemetry = ClusterTelemetry(enabled=telemetry)
-        meshes = None
-        if devices_per_replica is not None:
+        meshes = devices = None
+        if devices_per_replica == 1:
+            devices = jax.devices()
+        elif devices_per_replica is not None:
             from repro.distributed.sharding import make_serving_mesh
             devs = jax.devices()
             n_slices = max(1, len(devs) // devices_per_replica)
@@ -190,6 +194,7 @@ class ClusterFrontend:
             added replicas are configured exactly like the initial pool
             (same shared budget, params, and scheduler config)."""
             rep_mesh = mesh if meshes is None else meshes[i % len(meshes)]
+            device = None if devices is None else devices[i % len(devices)]
             # host-spill knobs default to the EngineConfig env-driven
             # defaults (REPRO_HOST_SPILL / REPRO_HOST_SPILL_PAGES) unless
             # set explicitly here
@@ -206,6 +211,7 @@ class ClusterFrontend:
                              share_prefix=share_prefix,
                              token_level_prefix=token_level_prefix,
                              mesh=rep_mesh, shard_axes=shard_axes,
+                             device=device,
                              **spill_kw),
                 draft=draft, kv_budget=budget)
             kw = dict(page_size=page_size, prefill_emits_first_token=True)

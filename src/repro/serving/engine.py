@@ -115,6 +115,11 @@ class EngineConfig:
     # serving_shard_plan).  None = single-device (unchanged path).
     mesh: object = None
     shard_axes: str = "model"
+    # Single-device placement: a jax Device commits this engine's params,
+    # page pools, block tables and every per-call input to that device, so
+    # several one-chip replicas in one process each run on their own chip.
+    # None = JAX's default device.  Exclusive with ``mesh``.
+    device: object = None
 
 
 @dataclasses.dataclass
@@ -163,7 +168,13 @@ class ServingEngine:
         # trace time, so the one-scan-per-decode-group and one-host-sync
         # contracts hold per shard by construction.
         self.mesh = self.ecfg.mesh
+        self.device = self.ecfg.device
+        if self.mesh is not None and self.device is not None:
+            raise ValueError("EngineConfig: set mesh or device, not both")
         self._shard_plan = None
+        if self.device is not None:
+            self.params = jax.device_put(self.params, self.device)
+            self.kv.place_on(self.device)
         if self.mesh is not None:
             from repro.distributed import sharding as shd
             self._shard_plan = shd.serving_shard_plan(
@@ -232,12 +243,11 @@ class ServingEngine:
         these specs), so jit inserts no resharding; everything else —
         tokens, positions, block tables, RNG keys, emitted tokens — is
         replicated, which keeps sampling identical on every shard and the
-        single host sync per group intact.  check_rep=False: replication
+        single host sync per group intact.  check_vma=False: replication
         of the outputs is by construction (identical math per shard), not
         statically inferrable through pallas/scatter ops."""
         import functools
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.distributed import sharding as shd
@@ -247,8 +257,8 @@ class ServingEngine:
         cspec = shd.serving_cache_specs(self.kv.pools, self.cfg, plan,
                                         lane_view=True)
         rep = P()
-        smap = functools.partial(shard_map, mesh=self.mesh,
-                                 check_rep=False)
+        smap = functools.partial(jax.shard_map, mesh=self.mesh,
+                                 check_vma=False)
         self._prefill = jax.jit(
             smap(self._prefill_forward,
                  in_specs=(pspec, rep, cspec, rep, rep, rep, rep, rep),
@@ -276,16 +286,18 @@ class ServingEngine:
                          enc_states, keys):
         """One lane-batched chunk group: each lane writes its chunk's KV
         into its own pages (per-lane block tables) and samples the token at
-        its last REAL position (true_len-1 of the padded chunk).  Padded
-        lanes carry true_len 0: their writes drop and output is ignored."""
+        its last REAL position (true_len-1 of the padded chunk) — only that
+        position's hidden state reaches the vocabulary projection, so the
+        step never holds (lanes x chunk x vocab) logits.  Padded lanes
+        carry true_len 0: their writes drop and output is ignored."""
         h, cache, _ = model_forward(params, self.cfg, tokens, cache=cache,
                                     pos0=pos0, enc_states=enc_states,
                                     moe_cf=self._moe_cf, block_tables=bt,
                                     chunk_len=true_len,
                                     shard=self._shard_plan)
-        logits = logits_fn(params, self.cfg, h)
         idx = jnp.maximum(true_len - 1, 0)
-        last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
+        h_last = jnp.take_along_axis(h, idx[:, None, None], axis=1)
+        last = logits_fn(params, self.cfg, h_last)[:, 0]
         toks = jax.vmap(
             lambda lg, k: sample(lg, k, self.ecfg.temperature))(last, keys)
         return toks, cache
@@ -341,6 +353,13 @@ class ServingEngine:
         (cache, _, pos, done, _), emitted = jax.lax.scan(
             step, carry0, jnp.arange(n_steps))
         return cache, emitted.T, pos, done                # emitted: (B, S)
+
+    def _put(self, x):
+        """A host value as a per-call device input, committed to this
+        engine's device when it has one."""
+        if self.device is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self.device)
 
     # ------------------------------------------------------------------ #
     def _share_tokens(self, tokens, enc_states):
@@ -613,9 +632,9 @@ class ServingEngine:
         from repro.models import attention as _attn
         ops0 = dict(_attn.OP_STATS)
         tok, cache = self._prefill(
-            self.params, jnp.asarray(toks), cache, jnp.asarray(pos0),
-            jnp.asarray(true_len), self.kv.table_rows(slots_p),
-            self._gather_enc(rids, B), jnp.stack(keys))
+            self.params, self._put(toks), cache, self._put(pos0),
+            self._put(true_len), self.kv.table_rows(slots_p),
+            self._gather_enc(rids, B), self._put(jnp.stack(keys)))
         self.counters["prefill_scatter_ops"] += (
             _attn.OP_STATS["paged_write"] - ops0["paged_write"])
         self.counters["prefill_attn_ops"] += (
@@ -697,15 +716,17 @@ class ServingEngine:
         pad = B - len(live)
         slots = [self.kv.seq_of[r] for r in live]
         slots_p = slots + [slots[0]] * pad
-        steps = jnp.asarray([steps_of[r] for r in live] + [0] * pad,
-                            jnp.int32)
+        steps = self._put(np.asarray(
+            [steps_of[r] for r in live] + [0] * pad, np.int32))
         starts = [self._last_token(r) for r in live]
-        toks0 = jnp.asarray(starts + [0] * pad, jnp.int32)
-        eos = jnp.asarray([self.reqs[r].eos if self.reqs[r].eos is not None
-                           else -1 for r in live] + [-1] * pad, jnp.int32)
-        pos0 = jnp.asarray(self.kv.seq_len[slots_p], jnp.int32)
+        toks0 = self._put(np.asarray(starts + [0] * pad, np.int32))
+        eos = self._put(np.asarray(
+            [self.reqs[r].eos if self.reqs[r].eos is not None else -1
+             for r in live] + [-1] * pad, np.int32))
+        pos0 = self._put(self.kv.seq_len[slots_p].astype(np.int32))
         cache = self.kv.lane_cache(slots_p)
         self.key, sk = jax.random.split(self.key)
+        sk = self._put(sk)
         cache, emitted, _, _ = self._decode(
             self.params, cache, toks0, pos0, steps, eos,
             self.kv.table_rows(slots_p), self._gather_enc(live, B), sk,

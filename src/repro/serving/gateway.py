@@ -187,8 +187,9 @@ class SSEGateway:
         await asyncio.sleep(0.05)
         self._accepting = False
         if self._server is not None:
+            # stop listening; Server.wait_closed() also waits for every
+            # open connection (Python >= 3.12.1), so it comes after drain
             self._server.close()
-            await self._server.wait_closed()
         if drain:
             for _ in range(max_steps):
                 if not self._live and self.cluster.idle:
@@ -208,6 +209,8 @@ class SSEGateway:
         conns = {t for t in self._conns if not t.done()}
         if conns:
             await asyncio.wait(conns, timeout=10.0)
+        if self._server is not None:
+            await self._server.wait_closed()
         if self._pump_task is not None:
             self._pump_task.cancel()
             with contextlib.suppress(asyncio.CancelledError):

@@ -404,6 +404,12 @@ class PagedKVManager(PageAllocator):
         self.block_tables = jax.device_put(self.block_tables,
                                            NamedSharding(mesh, P()))
 
+    def place_on(self, device) -> None:
+        """Commit the at-rest device state (pools, block tables) to one
+        device: a one-chip replica of a multi-chip host."""
+        self.pools = jax.device_put(self.pools, device)
+        self.block_tables = jax.device_put(self.block_tables, device)
+
     # ------------------------ physical page ops ------------------------- #
     @property
     def used_pages(self) -> int:

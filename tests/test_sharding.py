@@ -3,8 +3,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed.sharding import _fit, batch_spec, param_spec
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-POD_MESH = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_fit_drops_indivisible_axes():
@@ -68,8 +68,8 @@ from repro.distributed.sharding import (serving_cache_specs,
 from repro.models import init_params
 from repro.serving.kvcache import PagedKVManager
 
-M2 = AbstractMesh((("model", 2),))
-M4 = AbstractMesh((("model", 4),))
+M2 = AbstractMesh((2,), ("model",))
+M4 = AbstractMesh((4,), ("model",))
 
 
 def _tree(tree, path):
