@@ -37,7 +37,7 @@ from repro.core.router import RoutingPolicy
 from repro.core.scheduler import SchedulerConfig, SLOsServeScheduler
 from repro.models.config import ModelConfig
 from repro.serving.engine import EngineConfig, ServingEngine
-from repro.serving.frontend import ReplicaDriver
+from repro.serving.frontend import QUEUE_SPAN, ReplicaDriver
 from repro.serving.kvcache import SharedPageBudget
 from repro.telemetry.instruments import ClusterTelemetry
 
@@ -236,6 +236,8 @@ class ClusterFrontend:
     def submit(self, req: Request, prompt: Optional[list] = None,
                on_token: Optional[Callable] = None, enc_states=None) -> None:
         """Queue a request for routing at its arrival time."""
+        if self.telemetry is not None:
+            self.telemetry.tracer.begin(QUEUE_SPAN, req.rid)
         p = _Payload(req, prompt, on_token, enc_states)
         self.payloads[req.rid] = p
         self.pending.append(p)
@@ -254,6 +256,9 @@ class ClusterFrontend:
                 self.pending.remove(p)
                 self.payloads.pop(rid, None)
                 self._cancelled += 1
+                if self.telemetry is not None:
+                    self.telemetry.tracer.end(QUEUE_SPAN, rid,
+                                              outcome="cancelled")
                 return True
         self.payloads.pop(rid, None)
         for d in self.drivers:
@@ -360,6 +365,9 @@ class ClusterFrontend:
         else:
             self._dropped += 1
             self.payloads.pop(req.rid, None)
+            if self.telemetry is not None:
+                self.telemetry.tracer.end(QUEUE_SPAN, req.rid,
+                                          outcome="dropped")
 
     # --------------------- replica pool elasticity ---------------------- #
     def add_replica(self) -> ReplicaDriver:

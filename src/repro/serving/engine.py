@@ -197,7 +197,11 @@ class ServingEngine:
         else:
             self._build_sharded_programs()
         self.counters = {"prefill_calls": 0, "decode_calls": 0,
-                         "decode_tokens": 0, "spec_draft_calls": 0,
+                         "decode_tokens": 0,
+                         # decode scans: lanes x steps run (padded and
+                         # finished lanes included), and live lanes
+                         "decode_lane_steps": 0, "decode_live_lanes": 0,
+                         "spec_draft_calls": 0,
                          "spec_verify_calls": 0, "preemptions": 0,
                          "prefix_hit_tokens": 0,
                          # paged-KV ops inside freshly TRACED prefill
@@ -227,8 +231,9 @@ class ServingEngine:
         # steps — the frontend feeds these into its per-SLO-class
         # AcceptanceEstimator after each batch
         self.last_spec_stats: dict[int, tuple[int, int]] = {}
-        # optional StepTracer (telemetry): when set, execute() wraps the
-        # prefill / decode / verify dispatch in timing spans
+        # optional StepTracer (telemetry): when set, execute() times its
+        # phases in repro.engine.* spans (intake, then prepare / dispatch
+        # / wait / post per prefill group and decode call)
         self.tracer = None
         # speculative decoding: (draft_cfg, draft_params)
         self.spec = None
@@ -523,41 +528,40 @@ class ServingEngine:
         preempt best-effort victims (freeing real device pages) before the
         engine retries — failing that, prefill raises and decode caps its
         step budget, exactly as without the callback."""
-        # Overlap point for the host spill tier: admissions queued H2D
-        # prefetches; dispatch them all now as one async device copy, then
-        # do the host-side prefill grouping while the transfer is in
-        # flight (the functional pool update gives the prefill programs a
-        # data dependency on the prefetched content — never a stale read).
-        self.kv.flush_prefetch()
         emitted: dict[int, list] = {}
         self.last_prefill_progress = {}
         self.last_spec_stats = {}
-        prefills = []
-        decode_rids = []
-        for e in batch.entries:
-            if e.rid not in self.reqs:
-                continue
-            if e.kind == StageKind.PREFILL:
-                prefills.append((e.rid, e.n_tokens))
-            else:
-                decode_rids.append((e.rid, e.n_tokens))
-        with self._tspan("prefill", n=len(prefills)) if prefills \
-                else _NULL_CTX:
-            for group in self._group_prefills(prefills, on_pressure):
-                for rid, toks in self._prefill_group(*group).items():
-                    emitted.setdefault(rid, []).extend(toks)
+        with self._tspan("repro.engine.intake"):
+            # Overlap point for the host spill tier: admissions queued H2D
+            # prefetches; dispatch them all now as one async device copy,
+            # then do the host-side prefill grouping while the transfer is
+            # in flight (the functional pool update gives the prefill
+            # programs a data dependency on the prefetched content — never
+            # a stale read).
+            self.kv.flush_prefetch()
+            prefills = []
+            decode_rids = []
+            for e in batch.entries:
+                if e.rid not in self.reqs:
+                    continue
+                if e.kind == StageKind.PREFILL:
+                    prefills.append((e.rid, e.n_tokens))
+                else:
+                    decode_rids.append((e.rid, e.n_tokens))
+            groups = self._group_prefills(prefills, on_pressure)
+        for group in groups:
+            for rid, toks in self._prefill_group(*group).items():
+                emitted.setdefault(rid, []).extend(toks)
         if decode_rids:
             if batch.spec_step > 0 and self.spec is not None:
-                with self._tspan("verify", n=len(decode_rids)):
+                with self._tspan("repro.engine.verify"):
                     for rid, n in decode_rids:
                         emitted.setdefault(rid, []).extend(
                             self.spec.decode(rid, n, on_pressure))
             else:
-                with self._tspan("decode", n=len(decode_rids)):
-                    out = self._decode_batched(dict(decode_rids),
-                                               on_pressure)
-                    for rid, toks in out.items():
-                        emitted.setdefault(rid, []).extend(toks)
+                out = self._decode_batched(dict(decode_rids), on_pressure)
+                for rid, toks in out.items():
+                    emitted.setdefault(rid, []).extend(toks)
         return emitted
 
     def _tspan(self, name: str, **attrs):
@@ -605,68 +609,75 @@ class ServingEngine:
         """One lane-batched prefill forward for same-bucket chunks from
         different requests (per-lane block tables address each request's
         own pages): ONE jitted device call for the whole group."""
-        rids = [rid for rid, _, _ in recs]
-        slots = [self.kv.seq_of[r] for r in rids]
-        B = _bucket(len(recs), (1, 2, 4, 8))
-        pad = B - len(recs)
-        slots_p = slots + [slots[0]] * pad
-        toks = np.zeros((B, Lp), np.int32)
-        true_len = np.zeros((B,), np.int32)
-        pos0 = np.zeros((B,), np.int32)
-        keys = []
-        for i, (rid, chunk, pos) in enumerate(recs):
-            toks[i, :len(chunk)] = chunk
-            true_len[i] = len(chunk)
-            pos0[i] = pos
-            ctx = self.reqs[rid]
-            if ctx.pending or ctx.recompute:
-                # the sampled token will be discarded: don't advance the
-                # RNG stream — temperature>0 output must not depend on how
-                # the planner split the prefill (or on preemption replay)
-                keys.append(jax.random.PRNGKey(0))
-            else:
-                self.key, sk = jax.random.split(self.key)
-                keys.append(sk)
-        keys += [jax.random.PRNGKey(0)] * pad
-        cache = self.kv.lane_cache(slots_p)
-        from repro.models import attention as _attn
-        ops0 = dict(_attn.OP_STATS)
-        tok, cache = self._prefill(
-            self.params, self._put(toks), cache, self._put(pos0),
-            self._put(true_len), self.kv.table_rows(slots_p),
-            self._gather_enc(rids, B), self._put(jnp.stack(keys)))
-        self.counters["prefill_scatter_ops"] += (
-            _attn.OP_STATS["paged_write"] - ops0["paged_write"])
-        self.counters["prefill_attn_ops"] += (
-            _attn.OP_STATS["prefill_attn"] - ops0["prefill_attn"])
-        self.counters["prefill_fused_ops"] += (
-            _attn.OP_STATS["fused_prefill"] - ops0["fused_prefill"])
-        self.kv.absorb(slots, cache)
-        self.counters["prefill_calls"] += 1
-        tok_h = np.asarray(tok)
-        out: dict[int, list] = {}
-        for i, (rid, chunk, _) in enumerate(recs):
-            ctx = self.reqs[rid]
-            self.kv.seq_len[slots[i]] += len(chunk)
-            replayed = min(len(chunk), ctx.replay)
-            ctx.replay -= replayed
-            self.last_prefill_progress[rid] = len(chunk) - replayed
-            ctx.history.extend(chunk)
-            # publish completed prompt pages for later same-prefix
-            # requests; decode pages stay private (rollback may rewrite)
-            self.kv.register_prefix(rid, ctx.history)
-            if not ctx.pending:
-                if ctx.recompute:
-                    # recompute after preemption: the cache is restored
-                    # exactly; the next decode input is the last generated
-                    # token, so this re-sampled emission is discarded
-                    ctx.recompute = False
+        with self._tspan("repro.engine.prefill.prepare"):
+            rids = [rid for rid, _, _ in recs]
+            slots = [self.kv.seq_of[r] for r in rids]
+            B = _bucket(len(recs), (1, 2, 4, 8))
+            pad = B - len(recs)
+            slots_p = slots + [slots[0]] * pad
+            toks = np.zeros((B, Lp), np.int32)
+            true_len = np.zeros((B,), np.int32)
+            pos0 = np.zeros((B,), np.int32)
+            keys = []
+            for i, (rid, chunk, pos) in enumerate(recs):
+                toks[i, :len(chunk)] = chunk
+                true_len[i] = len(chunk)
+                pos0[i] = pos
+                ctx = self.reqs[rid]
+                if ctx.pending or ctx.recompute:
+                    # the sampled token will be discarded: don't advance
+                    # the RNG stream — temperature>0 output must not depend
+                    # on how the planner split the prefill (or on
+                    # preemption replay)
+                    keys.append(jax.random.PRNGKey(0))
                 else:
-                    # prefill complete: the last position's logits yield
-                    # the first output token (TTFT = time-to-FIRST-token)
-                    t = int(tok_h[i])
-                    ctx.generated.append(t)
-                    out[rid] = [t]
+                    self.key, sk = jax.random.split(self.key)
+                    keys.append(sk)
+            keys += [jax.random.PRNGKey(0)] * pad
+            args = (self.params, self._put(toks), self.kv.lane_cache(slots_p),
+                    self._put(pos0), self._put(true_len),
+                    self.kv.table_rows(slots_p), self._gather_enc(rids, B),
+                    self._put(jnp.stack(keys)))
+        from repro.models import attention as _attn
+        with self._tspan("repro.engine.prefill.dispatch"):
+            ops0 = dict(_attn.OP_STATS)
+            tok, cache = self._prefill(*args)
+            self.counters["prefill_scatter_ops"] += (
+                _attn.OP_STATS["paged_write"] - ops0["paged_write"])
+            self.counters["prefill_attn_ops"] += (
+                _attn.OP_STATS["prefill_attn"] - ops0["prefill_attn"])
+            self.counters["prefill_fused_ops"] += (
+                _attn.OP_STATS["fused_prefill"] - ops0["fused_prefill"])
+            self.counters["prefill_calls"] += 1
+        with self._tspan("repro.engine.prefill.wait"):
+            tok_h = np.asarray(tok)
+        with self._tspan("repro.engine.prefill.post"):
+            self.kv.absorb(slots, cache)
+            out: dict[int, list] = {}
+            for i, (rid, chunk, _) in enumerate(recs):
+                ctx = self.reqs[rid]
+                self.kv.seq_len[slots[i]] += len(chunk)
+                replayed = min(len(chunk), ctx.replay)
+                ctx.replay -= replayed
+                self.last_prefill_progress[rid] = len(chunk) - replayed
+                ctx.history.extend(chunk)
+                # publish completed prompt pages for later same-prefix
+                # requests; decode pages stay private (rollback may rewrite)
+                self.kv.register_prefix(rid, ctx.history)
+                if not ctx.pending:
+                    if ctx.recompute:
+                        # recompute after preemption: the cache is restored
+                        # exactly; the next decode input is the last
+                        # generated token, so this re-sampled emission is
+                        # discarded
+                        ctx.recompute = False
+                    else:
+                        # prefill complete: the last position's logits
+                        # yield the first output token (TTFT =
+                        # time-to-FIRST-token)
+                        t = int(tok_h[i])
+                        ctx.generated.append(t)
+                        out[rid] = [t]
         return out
 
     # ------------------------------------------------------------------ #
@@ -681,71 +692,82 @@ class ServingEngine:
                 and steps_of[r] > 0]
         if not live:
             return out
-        if on_pressure is not None:
-            # decode-step reservation against page exhaustion: report the
-            # shortfall so the frontend can preempt best-effort victims
-            # before the capping below trims the step budget
-            need = 0
+        with self._tspan("repro.engine.decode.reserve"):
+            if on_pressure is not None:
+                # decode-step reservation against page exhaustion: report
+                # the shortfall so the frontend can preempt best-effort
+                # victims before the capping below trims the step budget
+                need = 0
+                for r in live:
+                    want = min(self.kv.length(r) + steps_of[r],
+                               self.ecfg.max_len)
+                    need += max(0, self.kv.pages_needed(want)
+                                - len(self.kv.tables.get(r, [])))
+                short = need - self.kv.free_pages
+                if short > 0:
+                    on_pressure(short)
+            # Cap each lane's budget to the pages/context actually
+            # available (sequential: earlier lanes claim free pages first)
+            # rather than crashing the serving loop mid-stream; the planner
+            # sees the shortfall as fewer emitted tokens.
+            capped = {}
             for r in live:
-                want = min(self.kv.length(r) + steps_of[r],
-                           self.ecfg.max_len)
-                need += max(0, self.kv.pages_needed(want)
-                            - len(self.kv.tables.get(r, [])))
-            short = need - self.kv.free_pages
-            if short > 0:
-                on_pressure(short)
-        # Cap each lane's budget to the pages/context actually available
-        # (sequential: earlier lanes claim free pages first) rather than
-        # crashing the serving loop mid-stream; the planner sees the
-        # shortfall as fewer emitted tokens.
-        capped = {}
-        for r in live:
-            cur = self.kv.length(r)
-            n = min(steps_of[r], self.kv.token_capacity(r) - cur)
-            if n > 0:
-                self.kv.extend(r, cur + n)
-                self._cow_barrier(r, cur, n, on_pressure)
-                capped[r] = n
+                cur = self.kv.length(r)
+                n = min(steps_of[r], self.kv.token_capacity(r) - cur)
+                if n > 0:
+                    self.kv.extend(r, cur + n)
+                    self._cow_barrier(r, cur, n, on_pressure)
+                    capped[r] = n
         steps_of = capped
         live = [r for r in live if r in capped]
         if not live:
             return out
-        n_steps = _bucket(max(steps_of[r] for r in live),
-                          (1, 2, 4, 8, 16, 32, 64, 128, 256))
-        B = _bucket(len(live), (1, 2, 4, 8, 16, 32, 64, 128))
-        pad = B - len(live)
-        slots = [self.kv.seq_of[r] for r in live]
-        slots_p = slots + [slots[0]] * pad
-        steps = self._put(np.asarray(
-            [steps_of[r] for r in live] + [0] * pad, np.int32))
-        starts = [self._last_token(r) for r in live]
-        toks0 = self._put(np.asarray(starts + [0] * pad, np.int32))
-        eos = self._put(np.asarray(
-            [self.reqs[r].eos if self.reqs[r].eos is not None else -1
-             for r in live] + [-1] * pad, np.int32))
-        pos0 = self._put(self.kv.seq_len[slots_p].astype(np.int32))
-        cache = self.kv.lane_cache(slots_p)
-        self.key, sk = jax.random.split(self.key)
-        sk = self._put(sk)
-        cache, emitted, _, _ = self._decode(
-            self.params, cache, toks0, pos0, steps, eos,
-            self.kv.table_rows(slots_p), self._gather_enc(live, B), sk,
-            n_steps=n_steps)
-        self.counters["decode_calls"] += 1
-        self.kv.absorb(slots, cache)
-        em = np.asarray(emitted)                  # ONE host sync per group
-        for i, r in enumerate(live):
-            ctx = self.reqs[r]
-            toks = [int(t) for t in em[i, :steps_of[r]] if t >= 0]
-            ctx.generated.extend(toks)
-            # tokens written to KV this call: the start input + all but the
-            # last emission (whose KV lands on the next call)
-            ctx.history.extend(([starts[i]] + toks)[:len(toks)])
-            out[r].extend(toks)
-            self.kv.seq_len[slots[i]] += len(toks)
-            self.counters["decode_tokens"] += len(toks)
-            if ctx.eos is not None and toks and toks[-1] == ctx.eos:
-                ctx.done = True
+        with self._tspan("repro.engine.decode.prepare"):
+            n_steps = _bucket(max(steps_of[r] for r in live),
+                              (1, 2, 4, 8, 16, 32, 64, 128, 256))
+            B = _bucket(len(live), (1, 2, 4, 8, 16, 32, 64, 128))
+            pad = B - len(live)
+            slots = [self.kv.seq_of[r] for r in live]
+            slots_p = slots + [slots[0]] * pad
+            steps = self._put(np.asarray(
+                [steps_of[r] for r in live] + [0] * pad, np.int32))
+            starts = [self._last_token(r) for r in live]
+            toks0 = self._put(np.asarray(starts + [0] * pad, np.int32))
+            eos = self._put(np.asarray(
+                [self.reqs[r].eos if self.reqs[r].eos is not None else -1
+                 for r in live] + [-1] * pad, np.int32))
+            pos0 = self._put(self.kv.seq_len[slots_p].astype(np.int32))
+            cache = self.kv.lane_cache(slots_p)
+            self.key, sk = jax.random.split(self.key)
+            sk = self._put(sk)
+            bt = self.kv.table_rows(slots_p)
+            enc = self._gather_enc(live, B)
+        # lane_steps: every lane of the scan, padded and finished ones
+        # included, for every step — the work the device is handed
+        with self._tspan("repro.engine.decode.dispatch",
+                         lane_steps=n_steps * B):
+            cache, emitted, _, _ = self._decode(
+                self.params, cache, toks0, pos0, steps, eos, bt, enc, sk,
+                n_steps=n_steps)
+            self.counters["decode_calls"] += 1
+            self.counters["decode_lane_steps"] += n_steps * B
+            self.counters["decode_live_lanes"] += len(live)
+        with self._tspan("repro.engine.decode.wait"):
+            em = np.asarray(emitted)              # ONE host sync per group
+        with self._tspan("repro.engine.decode.post"):
+            self.kv.absorb(slots, cache)
+            for i, r in enumerate(live):
+                ctx = self.reqs[r]
+                toks = [int(t) for t in em[i, :steps_of[r]] if t >= 0]
+                ctx.generated.extend(toks)
+                # tokens written to KV this call: the start input + all but
+                # the last emission (whose KV lands on the next call)
+                ctx.history.extend(([starts[i]] + toks)[:len(toks)])
+                out[r].extend(toks)
+                self.kv.seq_len[slots[i]] += len(toks)
+                self.counters["decode_tokens"] += len(toks)
+                if ctx.eos is not None and toks and toks[-1] == ctx.eos:
+                    ctx.done = True
         return out
 
     def _gather_enc(self, rids, B):

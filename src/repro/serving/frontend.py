@@ -35,6 +35,11 @@ from repro.core.spec_planner import AcceptanceEstimator
 from repro.serving.engine import ServingEngine
 
 
+# one per request: from submission to the start of the first engine
+# batch that prefills it (``StepTracer.begin`` / ``end``)
+QUEUE_SPAN = "repro.request.queue"
+
+
 def _null_span(name, **attrs):
     return contextlib.nullcontext()
 
@@ -72,8 +77,10 @@ class ReplicaDriver:
         # bare driver pays nothing
         self.tel = telemetry
         self._span = _null_span
+        self._tracer = None
         if telemetry is not None and telemetry.tracer is not None \
                 and telemetry.tracer.enabled:
+            self._tracer = telemetry.tracer
             self._span = telemetry.tracer.span
             engine.tracer = telemetry.tracer
         self.rng = np.random.default_rng(seed)
@@ -138,6 +145,8 @@ class ReplicaDriver:
         self.saved_ctx.pop(rid, None)
 
     def drop_request(self, r: Request) -> None:
+        if self._tracer is not None:
+            self._tracer.end(QUEUE_SPAN, r.rid, outcome="dropped")
         self.stats.dropped += 1
         self.stats.served += 1
         if self.tel is not None:
@@ -171,6 +180,8 @@ class ReplicaDriver:
             self.engine.drop(rid)
             found = True
         if found:
+            if self._tracer is not None:
+                self._tracer.end(QUEUE_SPAN, rid, outcome="cancelled")
             self.stats.cancelled += 1
             self.preempted_rids.discard(rid)
             self.forget(rid)
@@ -262,7 +273,7 @@ class ReplicaDriver:
         self.new_q = [r for r in self.new_q if r.arrival > now]
         cached, live, pen = self._discounts(arrivals)
         t0 = time.perf_counter()
-        with self._span("plan", replica=self.idx):
+        with self._span("repro.driver.plan", replica=self.idx):
             plan = self.sched.plan(now, self.running, arrivals,
                                    self._mem_free(),
                                    cached_prefix=cached, live_prefix=live,
@@ -282,9 +293,7 @@ class ReplicaDriver:
         t = now
         by_rid = {r.rid: r for r in self.running}
         for b in plan.batches[:max_batches]:
-            with self._span("execute", replica=self.idx,
-                            n=len(b.entries), spec=b.spec_step):
-                out = self.engine.execute(b, on_pressure=self._preempt_for)
+            out = self._execute(b, on_pressure=self._preempt_for)
             t += max(b.est_duration, 1e-3)
             res.n_exec += 1
             pref_done = 0
@@ -333,6 +342,18 @@ class ReplicaDriver:
                 res.n_exec += 1
         res.elapsed = t - now
         return res
+
+    def _execute(self, b: Batch, on_pressure=None) -> dict[int, list]:
+        """``engine.execute`` under a ``repro.driver.execute`` span; the
+        batch's prefill entries end their requests' queue spans."""
+        if self._tracer is None:
+            return self.engine.execute(b, on_pressure=on_pressure)
+        with self._span("repro.driver.execute", replica=self.idx,
+                        n=len(b.entries), spec=b.spec_step):
+            for e in b.entries:
+                if e.kind == StageKind.PREFILL:
+                    self._tracer.end(QUEUE_SPAN, e.rid)
+            return self.engine.execute(b, on_pressure=on_pressure)
 
     def _sweep(self, by_rid: dict, t: float) -> None:
         eng = self.engine
@@ -530,7 +551,7 @@ class ReplicaDriver:
                 b = Batch()
                 b.add(rid, StageKind.PREFILL, take)
                 try:
-                    out = eng.execute(b)
+                    out = self._execute(b)
                 except RuntimeError:
                     # a copy-on-write target exceeded the capacity cap
                     # (token_capacity counts mapped+free pages, not the
@@ -554,7 +575,7 @@ class ReplicaDriver:
                 b = Batch()
                 b.add(rid, StageKind.DECODE, n)
                 try:
-                    out = eng.execute(b).get(rid, [])
+                    out = self._execute(b).get(rid, [])
                 except RuntimeError:
                     break                # CoW page short: back off
                 if not out:
@@ -621,6 +642,8 @@ class ServingFrontend:
                on_token: Optional[Callable] = None,
                enc_states=None) -> None:
         """Queue a request; ``on_token(rid, [tokens])`` streams output."""
+        if self.telemetry is not None:
+            self.telemetry.tracer.begin(QUEUE_SPAN, req.rid)
         self.driver.enqueue(req, prompt, on_token, enc_states)
         self.driver.stats.submitted += 1
 

@@ -4,8 +4,8 @@ attainment-driven autoscaler.  Dependency-free by design (see
 registry.py); zero overhead when ``REPRO_METRICS`` is off."""
 from repro.telemetry.autoscaler import (Autoscaler, AutoscalerConfig,
                                         ScaleDecision)
-from repro.telemetry.exporters import (StepTracer, histogram_percentiles,
-                                       parse_prometheus, prometheus_text,
+from repro.telemetry.exporters import (StepTracer, parse_prometheus,
+                                       prometheus_text,
                                        quantile_from_exposition,
                                        timeseries_prometheus_text)
 from repro.telemetry.instruments import (ClusterTelemetry, PlanTimer,

@@ -1,5 +1,5 @@
-"""Telemetry exporters: Prometheus text exposition and a JSONL step
-tracer with span-style timing hooks.
+"""Telemetry exporters: Prometheus text exposition and an in-memory step
+tracer with span-style timing hooks, serialised as JSON lines.
 
 ``prometheus_text`` renders a ``MetricsRegistry`` in the Prometheus text
 exposition format (``# HELP`` / ``# TYPE`` headers, ``le``-labeled
@@ -7,24 +7,24 @@ histogram buckets with ``_sum``/``_count``); ``parse_prometheus`` is the
 matching minimal parser used by tests and the dashboard tooling, so the
 round trip is covered in-repo without a client-library dependency.
 
-``StepTracer`` writes one JSON object per line: ``step`` records (the
-per-step sampler row) and ``span`` records (wall-clock timing around
-plan / prefill / decode / verify, via the ``span`` context manager).
-With ``REPRO_JAX_TRACE=1`` each span additionally opens a
-``jax.profiler.TraceAnnotation`` so device profiles carry the same
-labels.
+``StepTracer`` keeps ``step`` records (the per-step sampler row) and
+``span`` records (host phases such as ``repro.driver.plan`` or
+``repro.engine.decode.wait``, each with start, end, parent span and
+request id on ``time.perf_counter_ns``) in memory, and writes them as
+JSON lines at ``dump()`` / ``close()``.  While a tracer is enabled each
+nested span also opens a ``jax.profiler.TraceAnnotation``, so a device
+profile taken meanwhile carries the same labels.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import math
-import os
 import time
-from typing import Optional, TextIO
+import weakref
+from typing import NamedTuple, Optional
 
-from repro.telemetry.registry import (Histogram, MetricsRegistry,
-                                      _HistogramChild)
+from repro.telemetry.registry import Histogram, MetricsRegistry
 
 
 def _fmt(v: float) -> str:
@@ -189,84 +189,154 @@ def quantile_from_exposition(samples: dict, name: str, q: float,
     return buckets[-1][0]
 
 
-def _jax_trace_enabled() -> bool:
-    return os.environ.get("REPRO_JAX_TRACE", "").lower() in ("1", "true",
-                                                             "on", "yes")
+
+
+class Span(NamedTuple):
+    """One timed host phase, on ``time.perf_counter_ns``."""
+    name: str
+    start_ns: int
+    end_ns: Optional[int]     # None while an async span is still open
+    id: int
+    parent: int               # id of the enclosing span, -1 for none
+    rid: Optional[int]        # the request it belongs to, if just one
+    attrs: dict
+
+
+class StepRecord(NamedTuple):
+    step: int
+    t: float                  # virtual seconds
+    wall_ns: int              # perf_counter_ns when recorded
+    row: dict
+
+
+# Temporary: the benchmark's run record does not carry the cluster's
+# tracer yet, so its metric readers find it here.  Delete ``_LIVE`` and
+# ``live_tracers`` once ``bench/lib/record.py`` ``Run`` holds the tracer.
+_LIVE: "weakref.WeakSet[StepTracer]" = weakref.WeakSet()
+
+
+def live_tracers() -> list["StepTracer"]:
+    """Every enabled tracer alive in this process (temporary, see
+    ``_LIVE``)."""
+    return list(_LIVE)
 
 
 class StepTracer:
-    """JSONL step trace: one JSON object per line.
+    """In-memory trace of a serving stack, serialised as JSON lines.
 
-    Records are dicts with a ``kind`` field: ``"step"`` rows snapshot
-    the per-step sampler output, ``"span"`` rows time named phases
-    (plan / prefill / decode / verify) in wall-clock seconds.  Lines are
-    buffered in memory (bounded) and optionally streamed to ``path``;
-    ``dump()`` returns the full JSONL blob for tests and benchmarks.
+    Three record kinds: ``step`` rows snapshot the per-step sampler
+    output (virtual time ``t`` plus the host clock ``wall_ns``), ``span``
+    records time named host phases (start, end, parent span, request
+    id), and free-form records come through ``emit``.  Records are kept
+    as tuples (bounded by ``max_records``; ``dropped`` counts the rest)
+    and turned into JSON only by ``records()``, ``dump()`` and
+    ``close()``, which writes them to ``path``.  A disabled tracer
+    records nothing.
     """
 
-    def __init__(self, path: Optional[str] = None, max_lines: int = 100_000,
-                 enabled: bool = True):
+    def __init__(self, path: Optional[str] = None,
+                 max_records: int = 100_000, enabled: bool = True):
         self.enabled = enabled
-        self.lines: list[str] = []
-        self.max_lines = max_lines
+        self.path = path
+        self.max_records = max_records
         self.dropped = 0
-        self._fh: Optional[TextIO] = None
-        if path is not None and enabled:
-            self._fh = open(path, "w")
-        self._jax_trace = _jax_trace_enabled()
+        self._recs: list = []
+        self._stack: list[int] = []     # ids of the open context spans
+        self._open: dict = {}           # (name, rid) -> start_ns
+        self._next_id = 0
+        if enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            _LIVE.add(self)
+
+    def _add(self, rec) -> None:
+        if len(self._recs) < self.max_records:
+            self._recs.append(rec)
+        else:
+            self.dropped += 1
 
     def emit(self, record: dict) -> None:
-        if not self.enabled:
-            return
-        line = json.dumps(record, separators=(",", ":"), sort_keys=True)
-        if len(self.lines) < self.max_lines:
-            self.lines.append(line)
-        else:
-            self.dropped += 1     # bounded memory; file keeps streaming
-        if self._fh is not None:
-            self._fh.write(line + "\n")
+        if self.enabled:
+            self._add(dict(record))
 
     def step(self, step: int, now: float, row: dict) -> None:
-        self.emit({"kind": "step", "step": step, "t": round(now, 6), **row})
+        if self.enabled:
+            self._add(StepRecord(step, now, time.perf_counter_ns(), row))
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        """Time a phase; emits a ``span`` record with wall-clock ``dur``.
-        No-op (zero records, near-zero cost) when disabled."""
+    def span(self, name: str, rid: Optional[int] = None, **attrs):
+        """Time a phase nested in the open spans.  The phase also runs
+        under a ``jax.profiler.TraceAnnotation`` of the same name, so a
+        device profile taken meanwhile holds a copy of the span on the
+        device trace's clock."""
         if not self.enabled:
             yield
             return
-        ctx = contextlib.nullcontext()
-        if self._jax_trace:
-            import jax
-            ctx = jax.profiler.TraceAnnotation(name)
-        t0 = time.perf_counter()
-        with ctx:
-            yield
-        self.emit({"kind": "span", "name": name,
-                   "dur": time.perf_counter() - t0, **attrs})
+        sid = self._next_id
+        self._next_id += 1
+        parent = self._stack[-1] if self._stack else -1
+        self._stack.append(sid)
+        t0 = time.perf_counter_ns()
+        try:
+            with self._annotation(name):
+                yield
+        finally:
+            self._stack.pop()
+            self._add(Span(name, t0, time.perf_counter_ns(), sid, parent,
+                           rid, attrs))
+
+    def begin(self, name: str, rid: int) -> None:
+        """Open an async span of one request, closed by ``end`` from any
+        later point of the program.  It does not nest in the call stack,
+        so it has no parent and no profiler copy."""
+        if self.enabled:
+            self._open[name, rid] = time.perf_counter_ns()
+
+    def end(self, name: str, rid: int, **attrs) -> None:
+        """Close the async span ``begin`` opened; no-op if none is open."""
+        if not self.enabled:
+            return
+        t0 = self._open.pop((name, rid), None)
+        if t0 is None:
+            return
+        sid = self._next_id
+        self._next_id += 1
+        self._add(Span(name, t0, time.perf_counter_ns(), sid, -1, rid,
+                       attrs))
+
+    def _still_open(self) -> list[Span]:
+        return [Span(name, t0, None, -1, -1, rid, {})
+                for (name, rid), t0 in self._open.items()]
+
+    def spans(self) -> list[Span]:
+        """Closed spans in the order they closed, then the async spans
+        still open (``end_ns`` None)."""
+        return [r for r in self._recs if isinstance(r, Span)] \
+            + self._still_open()
 
     def records(self, kind: Optional[str] = None) -> list[dict]:
-        recs = [json.loads(line) for line in self.lines]
-        if kind is not None:
-            recs = [r for r in recs if r.get("kind") == kind]
+        recs = []
+        for r in self._recs + self._still_open():
+            if isinstance(r, Span):
+                d = {"kind": "span", "name": r.name,
+                     "start_ns": r.start_ns, "end_ns": r.end_ns,
+                     "id": r.id, "parent": r.parent, "rid": r.rid,
+                     **r.attrs}
+            elif isinstance(r, StepRecord):
+                d = {"kind": "step", "step": r.step, "t": round(r.t, 6),
+                     "wall_ns": r.wall_ns, **r.row}
+            else:
+                d = r
+            if kind is None or d.get("kind") == kind:
+                recs.append(d)
         return recs
 
     def dump(self) -> str:
-        return "\n".join(self.lines) + ("\n" if self.lines else "")
+        lines = [json.dumps(r, separators=(",", ":"), sort_keys=True)
+                 for r in self.records()]
+        return "\n".join(lines) + ("\n" if lines else "")
 
     def close(self) -> None:
-        if self._fh is not None:
-            self._fh.close()
-            self._fh = None
-
-
-def histogram_percentiles(m: Histogram, qs=(0.5, 0.9, 0.99)
-                          ) -> dict[str, dict[float, float]]:
-    """Readable percentile summary per labeled child of a histogram."""
-    out = {}
-    for lv, child in m.samples():
-        assert isinstance(child, _HistogramChild)
-        key = ",".join(f"{k}={v}" for k, v in lv.items()) or "_"
-        out[key] = child.percentiles(qs)
-    return out
+        if self.path is not None and self.enabled:
+            with open(self.path, "w") as f:
+                f.write(self.dump())

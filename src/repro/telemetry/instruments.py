@@ -11,8 +11,8 @@ the names defined HERE.
 
 Time base: request-facing latencies (TTFT, TPOT) and the step series are
 in **virtual seconds** (the planner's deterministic clock); ``span``
-records and plan latency are **wall-clock** (they measure real host/
-device work).
+records, the step records' ``wall_ns`` and plan latency are on the host's
+``perf_counter`` clock (they measure real host/device work).
 """
 from __future__ import annotations
 
@@ -232,6 +232,7 @@ class ClusterTelemetry:
 
     # ------------------------------------------------------------------ #
     _ENGINE_EVENTS = ("prefill_calls", "decode_calls", "decode_tokens",
+                      "decode_lane_steps", "decode_live_lanes",
                       "preemptions", "prefix_hit_tokens",
                       "spec_accepted_tokens", "spec_drafted_tokens")
     _KV_EVENTS = ("cow_copies", "prefix_evictions", "partial_hit_tokens",

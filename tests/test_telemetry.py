@@ -166,14 +166,22 @@ def test_step_tracer_records_and_span(tmp_path):
     path = tmp_path / "trace.jsonl"
     tr = StepTracer(path=str(path))
     tr.step(0, 1.5, {"replicas": 2.0})
-    with tr.span("plan", replica=0):
-        pass
+    with tr.span("repro.driver.plan", replica=0):
+        with tr.span("repro.engine.intake", rid=7):
+            pass
     tr.close()
     recs = tr.records()
+    wall = recs[0].pop("wall_ns")
     assert recs[0] == {"kind": "step", "step": 0, "t": 1.5,
                        "replicas": 2.0}
-    assert recs[1]["kind"] == "span" and recs[1]["name"] == "plan"
-    assert recs[1]["dur"] >= 0.0
+    inner, outer = recs[1], recs[2]        # in the order they closed
+    assert outer["kind"] == "span" and outer["name"] == "repro.driver.plan"
+    assert outer["replica"] == 0 and outer["parent"] == -1
+    assert outer["rid"] is None
+    assert inner["parent"] == outer["id"] and inner["rid"] == 7
+    assert wall <= outer["start_ns"] <= inner["start_ns"] \
+        <= inner["end_ns"] <= outer["end_ns"]
+    recs[0]["wall_ns"] = wall
     on_disk = [json.loads(line) for line in
                path.read_text().strip().splitlines()]
     assert on_disk == recs
@@ -380,7 +388,7 @@ def test_e2e_prometheus_matches_cluster_stats(tiny_cluster_parts):
     assert last["attained_total"] == stats.attained
     # span records cover the plan/execute phases
     names = {r["name"] for r in cl.telemetry.tracer.records("span")}
-    assert "plan" in names and "execute" in names
+    assert "repro.driver.plan" in names and "repro.driver.execute" in names
     # as_dict carries the guarded ratios
     d = stats.as_dict()
     assert d["attainment"] == pytest.approx(stats.attained / stats.served)
