@@ -1,7 +1,8 @@
 """Fig. 15: scheduling overhead per planning call (target: <10 ms,
 majority <2 ms — paper §6.4), plus the engine-side device-call audit:
 the paged runtime must issue exactly ONE jitted computation per decode
-batch group (the fused lax.scan), however many tokens the group spans."""
+batch group (the fused lax.scan) whose step budget fits a step bucket
+(<= 256 tokens); a longer budget runs as a chain of <= 256-step scans."""
 from __future__ import annotations
 
 import numpy as np
@@ -22,8 +23,9 @@ def run(rate: float = 6.0, duration: float = 30.0):
 
 def run_engine_device_calls(n_decode_tokens: int = 16):
     """Count jitted device computations on the real paged engine: one
-    prefill call per chunk, one decode call per batch group — O(1) host
-    round-trips where the dense-slot engine paid O(tokens)."""
+    prefill call per chunk, one decode call per batch group of at most
+    256 steps (one per <= 256-step scan beyond) — O(1) host round-trips
+    per 256 tokens where the dense-slot engine paid O(tokens)."""
     import jax
 
     from repro.configs import get_reduced

@@ -7,10 +7,13 @@ The engine executes planner ``Batch`` objects (Eqn. 1 entries):
     (chunked prefill: any split the planner chose), padded to bucket sizes
     to bound recompilation.  KV lands directly in the page pools — there is
     no per-request cache slot to gather or scatter.
-  * DECODE entries emit tokens autoregressively.  All requested steps for
-    a batch group run as ONE jitted ``lax.scan`` on device — sampling, EOS
-    masking, position advance and page writes included — and only the
-    final (B, n_steps) token matrix crosses back to the host.  With
+  * DECODE entries emit tokens autoregressively.  A batch group whose
+    longest step budget fits a step bucket (<= 256) runs as ONE jitted
+    ``lax.scan`` on device — sampling, EOS masking, position advance and
+    page writes included — and only the final (B, n_steps) token matrix
+    crosses back to the host.  A longer budget runs as a chain of
+    <= 256-step scans with one host sync each; lanes that finished drop
+    out between scans.  With
     ``spec_step > 0`` and an attached draft model, decoding goes through
     the speculative draft+verify executor (serving/spec_decode.py).
 
@@ -20,8 +23,9 @@ per-layer page pools + device block tables the model reads through.
 Engine capacity is bounded by pages, not by max_slots × max_len slabs.
 
 ``counters`` tracks jitted device computations (prefill_calls,
-decode_calls, decode_tokens) so benchmarks/overhead.py can assert the
-one-device-call-per-decode-group invariant.
+decode_calls, decode_tokens, decode_split_scans) so benchmarks/overhead.py
+can assert one device call per decode scan, and one scan per decode group
+whose budget fits a step bucket.
 """
 from __future__ import annotations
 
@@ -43,6 +47,11 @@ from repro.serving.sampling import sample
 
 
 _NULL_CTX = contextlib.nullcontext()    # reusable: nullcontext is stateless
+
+
+# decode scan lengths: a group's budget past the last runs as a chain of
+# scans (``ServingEngine._decode_batched``)
+_DECODE_STEPS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 
 
 def _bucket(n: int, buckets=(8, 16, 32, 64, 128, 256, 512, 1024, 2048)) -> int:
@@ -165,8 +174,9 @@ class ServingEngine:
         # Mesh-sharded serving: place params + at-rest pools per the
         # serving shard plan and wrap the three jitted programs in
         # shard_map.  The plan is read by model_forward (shard=...) at
-        # trace time, so the one-scan-per-decode-group and one-host-sync
-        # contracts hold per shard by construction.
+        # trace time, so the one-host-sync-per-decode-scan contract (and
+        # the chain of scans of a long budget) holds per shard by
+        # construction.
         self.mesh = self.ecfg.mesh
         self.device = self.ecfg.device
         if self.mesh is not None and self.device is not None:
@@ -201,6 +211,9 @@ class ServingEngine:
                          # decode scans: lanes x steps run (padded and
                          # finished lanes included), and live lanes
                          "decode_lane_steps": 0, "decode_live_lanes": 0,
+                         # scans a decode group ran beyond its first (its
+                         # budget outran the largest step bucket)
+                         "decode_split_scans": 0,
                          "spec_draft_calls": 0,
                          "spec_verify_calls": 0, "preemptions": 0,
                          "prefix_hit_tokens": 0,
@@ -682,8 +695,15 @@ class ServingEngine:
 
     # ------------------------------------------------------------------ #
     def _decode_batched(self, steps_of, on_pressure=None) -> dict[int, list]:
-        """steps_of: {rid: n_steps} or list of rids (1 step each).  One
-        jitted device computation for the whole group."""
+        """steps_of: {rid: n_steps} or list of rids (1 step each).
+
+        Pages for the group's whole capped budget are reserved once, up
+        front.  A group whose longest budget fits a step bucket (<= 256)
+        then runs as ONE jitted scan with one host sync.  A longer budget
+        runs as a chain of scans of at most 256 steps, one host sync each:
+        tokens and positions pass between scans on the host, lanes that
+        hit EOS or spent their budget drop out, and the rest re-bucket,
+        so a scan pads its steps and lanes to its own buckets at most."""
         if not isinstance(steps_of, dict):
             steps_of = {r: 1 for r in steps_of}
         out = {r: [] for r in steps_of}
@@ -710,21 +730,34 @@ class ServingEngine:
             # available (sequential: earlier lanes claim free pages first)
             # rather than crashing the serving loop mid-stream; the planner
             # sees the shortfall as fewer emitted tokens.
-            capped = {}
+            left = {}
             for r in live:
                 cur = self.kv.length(r)
                 n = min(steps_of[r], self.kv.token_capacity(r) - cur)
                 if n > 0:
                     self.kv.extend(r, cur + n)
                     self._cow_barrier(r, cur, n, on_pressure)
-                    capped[r] = n
-        steps_of = capped
-        live = [r for r in live if r in capped]
-        if not live:
-            return out
+                    left[r] = n
+        while left:
+            for r, toks in self._decode_scan_call(left).items():
+                out[r].extend(toks)
+                left[r] -= len(toks)
+            left = {r: n for r, n in left.items()
+                    if n > 0 and not self.reqs[r].done}
+            if left:
+                self.counters["decode_split_scans"] += 1
+        return out
+
+    def _decode_scan_call(self, steps_of) -> dict[int, list]:
+        """One decode scan over the lanes of ``steps_of`` ({rid: steps
+        left}, pages already reserved): ONE jitted device call and ONE
+        host sync.  It runs the step bucket of the longest budget, capped
+        at the largest bucket; a lane with more steps left than that
+        emits a bucket's worth and the caller scans again."""
+        live = list(steps_of)
         with self._tspan("repro.engine.decode.prepare"):
-            n_steps = _bucket(max(steps_of[r] for r in live),
-                              (1, 2, 4, 8, 16, 32, 64, 128, 256))
+            n_steps = _bucket(min(max(steps_of.values()), _DECODE_STEPS[-1]),
+                              _DECODE_STEPS)
             B = _bucket(len(live), (1, 2, 4, 8, 16, 32, 64, 128))
             pad = B - len(live)
             slots = [self.kv.seq_of[r] for r in live]
@@ -753,7 +786,8 @@ class ServingEngine:
             self.counters["decode_lane_steps"] += n_steps * B
             self.counters["decode_live_lanes"] += len(live)
         with self._tspan("repro.engine.decode.wait"):
-            em = np.asarray(emitted)              # ONE host sync per group
+            em = np.asarray(emitted)              # ONE host sync per scan
+        out = {}
         with self._tspan("repro.engine.decode.post"):
             self.kv.absorb(slots, cache)
             for i, r in enumerate(live):
@@ -763,7 +797,7 @@ class ServingEngine:
                 # tokens written to KV this call: the start input + all but
                 # the last emission (whose KV lands on the next call)
                 ctx.history.extend(([starts[i]] + toks)[:len(toks)])
-                out[r].extend(toks)
+                out[r] = toks
                 self.kv.seq_len[slots[i]] += len(toks)
                 self.counters["decode_tokens"] += len(toks)
                 if ctx.eos is not None and toks and toks[-1] == ctx.eos:

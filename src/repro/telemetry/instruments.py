@@ -233,6 +233,7 @@ class ClusterTelemetry:
     # ------------------------------------------------------------------ #
     _ENGINE_EVENTS = ("prefill_calls", "decode_calls", "decode_tokens",
                       "decode_lane_steps", "decode_live_lanes",
+                      "decode_split_scans",
                       "preemptions", "prefix_hit_tokens",
                       "spec_accepted_tokens", "spec_drafted_tokens")
     _KV_EVENTS = ("cow_copies", "prefix_evictions", "partial_hit_tokens",
