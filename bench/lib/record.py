@@ -2,11 +2,11 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from types import ModuleType
+from typing import Hashable, Optional
 
 from bench.lib import roofline
 from bench.lib.judge import Outcome
-from bench.lib.model import Dims
 
 
 @dataclasses.dataclass
@@ -19,7 +19,8 @@ class Run:
     counters: dict           # engine counters over the window
     plan: Optional[tuple]    # (planner wall s, calls) over the window
     trace: Optional[dict]    # bench.lib.trace.reduce of the window
-    dims: Dims
+    family: ModuleType       # bench/families/<family>.py of the config
+    dims: Hashable           # its sizes, ``family.dims(cfg_file)``
     peaks: dict
     # (kind, perf_counter, pos0, steps or chunk lengths, n_steps) of each
     # kernel-bearing engine call, in traced runs
@@ -48,14 +49,18 @@ class Run:
                 k += n
         return ctx
 
-    def calls_in_window(self, kind: str) -> list:
-        return [c for c in self.calls
-                if c[0] == kind and self.traced_from <= c[1] <= self.t_end]
-
-    def kernel_roofline_pct(self, kernel: str, costs) -> Optional[float]:
-        """Least time of the counted work over the kernel's device time,
-        in percent; None without a trace or without the kernel in it."""
-        if self.trace is None or not costs:
+    def kernel_roofline_pct(self, kernel: str) -> Optional[float]:
+        """Least time of the work the family counts for ``kernel`` in the
+        calls of the traced window over the kernel's device time, in
+        percent; None without a trace, for a kernel the family does not
+        name, or without the kernel's calls or time in the window."""
+        cost = self.family.KERNELS.get(kernel)
+        if self.trace is None or cost is None:
+            return None
+        costs = [c for c in (cost(self.dims, call) for call in self.calls
+                             if self.traced_from <= call[1] <= self.t_end)
+                 if c is not None]
+        if not costs:
             return None
         dev = self.trace["op_s"].get(kernel, 0.0)
         if dev <= 0:
@@ -65,8 +70,8 @@ class Run:
         return 100.0 * least / dev
 
     def model_flops(self) -> int:
-        d = self.dims
-        return (sum(roofline.prefill_model_flops(d, p)
+        f, d = self.family, self.dims
+        return (sum(f.prefill_model_flops(d, p)
                     for p in self.prompts_done())
-                + sum(roofline.decode_model_flops(d, c)
+                + sum(f.decode_model_flops(d, c)
                       for c in self.decode_contexts()))

@@ -4,22 +4,34 @@ Everything that belongs to one configuration, traffic mix, cell or metric
 sits in a file of its own, and the harness finds it by the name that
 ``BENCHMARK.json`` gives:
 
-  bench/configs/<config>.json    model sizes as run, and the zero-load fit
+  bench/configs/<config>.json    model sizes as run, the zero-load fit,
+                                 and ``"family"``: the model code's name
+  bench/families/<family>.py     one model family: ``dims``,
+                                 ``program_config``, ``make_weights``,
+                                 the plain reference (``served_gaps``,
+                                 ``control_gaps``), the model FLOPs
+                                 (``prefill_model_flops``,
+                                 ``decode_model_flops``) and ``KERNELS``,
+                                 each kernel's (ops, bytes) of one call
   bench/traffic/<traffic>.json   the mix: classes, lengths, clip, drain
   bench/cells/<workload>.json    the cell's fixed arrival rate (its knee)
   bench/metrics/<metric>.py      one reader per metric, ``read(run)``
 
-A later change adds a configuration, a mix, a cell or a metric by adding
-files and entries, never by editing these.
+A later change adds a configuration (of a family there or a new one), a
+mix, a cell or a metric by adding files and entries, never by editing
+these.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+FAMILIES = BENCH / "families"
 
 
 def load_json(path: Path) -> dict:
@@ -61,13 +73,30 @@ def metrics_of(spec: dict, cell: str, section: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
+@functools.cache
+def _load(path: Path, mod_name: str):
+    if not path.is_file():
+        raise FileNotFoundError(path)
+    s = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(s)
+    # registered before it runs: a dataclass resolves its module by name
+    sys.modules[mod_name] = mod
+    s.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str):
     """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    mod_name = "bench_metric_" + name.replace(".", "_").replace("-", "_")
-    s = importlib.util.spec_from_file_location(mod_name, path)
-    if s is None or s.loader is None:
-        raise FileNotFoundError(path)
-    mod = importlib.util.module_from_spec(s)
-    s.loader.exec_module(mod)
-    return mod.read
+    return _load(BENCH / "metrics" / f"{name}.py",
+                 "bench_metric_" + name.replace(".", "_").replace("-", "_")
+                 ).read
+
+
+def family(cfg_file: dict, directory: Path = FAMILIES):
+    """The module ``<directory>/<family>.py`` that the configuration file
+    names under ``"family"``; a file that names none, or a family with no
+    module, is an error."""
+    name = cfg_file.get("family")
+    if not (isinstance(name, str) and name.isidentifier()):
+        raise ValueError(f"the configuration file names no family: {name!r}")
+    return _load(Path(directory) / f"{name}.py", "bench_family_" + name)

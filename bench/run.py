@@ -2,13 +2,15 @@
 
   python3 bench/run.py --workload NAME --seed N --seconds S --trace 0|1
 
-builds the cell's model through the program's own entry with weights
-from the seed, warms every shape its traffic can reach, serves the
-traffic open-loop on the wall clock for ``--seconds``, checks the served
-tokens against the plain reference, and prints one JSON line last on
-standard output.  ``--trace 0`` reports the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics from a profiler trace of the window.
-It exits 3, printing no result, where JAX finds no TPU or too few chips.
+builds the cell's model through the program's own entry, with the
+program config and the weights from the seed that its configuration's
+model family gives (``bench/families/<family>.py``), warms every shape
+its traffic can reach, serves the traffic open-loop on the wall clock
+for ``--seconds``, checks the served tokens against the family's plain
+reference, and prints one JSON line last on standard output.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics from a profiler trace of the window.  It exits 3,
+printing no result, where JAX finds no TPU or too few chips.
 
 Modes the benchmark's own runs do not use, to set the cell files:
 
@@ -62,23 +64,30 @@ def ttft_fit(cfg_file: dict):
     return lambda p: z["a"] + z["b"] * p
 
 
-def setup(cfg_file, name, seed, telemetry, full=True):
-    from bench.lib import driver, model
-    dims = model.dims(cfg_file)
-    params = model.make_weights(dims, seed, model.dtype_of(cfg_file))
-    cluster = driver.build(model.program_config(cfg_file, name), params,
+def dtype_of(cfg_file: dict):
+    import jax.numpy as jnp
+    return {"float32": jnp.float32,
+            "bfloat16": jnp.bfloat16}[cfg_file["config"]["torch_dtype"]]
+
+
+def setup(cfg_file, fam, name, seed, telemetry, full=True):
+    """The cluster serving the weights of ``seed``, built through the
+    model family ``fam`` (``spec.family(cfg_file)``)."""
+    from bench.lib import driver
+    dims = fam.dims(cfg_file)
+    params = fam.make_weights(dims, seed, dtype_of(cfg_file))
+    cluster = driver.build(fam.program_config(cfg_file, name), params,
                            seed, telemetry, full)
     return dims, cluster
 
 
-def check(outs, prompts, dims, seed, limits, w32=None, control=False):
+def check(outs, prompts, fam, dims, seed, limits, w32=None, control=False):
     """Compare a sample of the finished requests, drawn from the seed with
-    the longest among them, against the plain reference.  Returns the
-    readings: the widest gap of a served token's reference logit below
+    the longest among them, against the family's plain reference.  Returns
+    the readings: the widest gap of a served token's reference logit below
     the reference's best (and, with ``control``, the control's)."""
     import numpy as np
 
-    from bench.lib import reference
     done = [o for o in outs if o.finished and not o.dropped]
     if not done:
         return {"sampled_requests": 0, "sampled_tokens": 0}
@@ -90,11 +99,11 @@ def check(outs, prompts, dims, seed, limits, w32=None, control=False):
     gap, cgap, n = 0.0, 0.0, 0
     for o in pick:
         served = o.tokens[:o.out_len]
-        g = reference.served_gaps(dims, w32, prompts[o.rid], served)
+        g = fam.served_gaps(dims, w32, prompts[o.rid], served)
         gap = max(gap, float(g.max()))
         n += len(served)
         if control:
-            cg = reference.control_gaps(dims, w32, prompts[o.rid], served)
+            cg = fam.control_gaps(dims, w32, prompts[o.rid], served)
             cgap = max(cgap, float(cg.max()))
     out = {"sampled_requests": len(pick), "sampled_tokens": n,
            "max_logit_gap": gap}
@@ -116,15 +125,14 @@ def verdict(got: dict, limits: dict) -> tuple[bool, dict]:
     return ok, checks
 
 
-def serve(cfg_file, traffic, rate, seconds, seed, name, drain_s, trace,
+def serve(cfg_file, fam, traffic, rate, seconds, seed, name, drain_s, trace,
           full=True, on_cluster=None):
     """Set up, warm, and run one window.  Returns (run record pieces)."""
     from bench.lib import driver, traffic as gen
-    from bench.lib.model import dims as dims_of
-    vocab = dims_of(cfg_file).vocab
-    reqs = gen.generate(traffic, rate, seconds, seed, vocab)
+    reqs = gen.generate(traffic, rate, seconds, seed,
+                        fam.dims(cfg_file).vocab)
     t = time.perf_counter()
-    dims, cluster = setup(cfg_file, name, seed, trace, full)
+    dims, cluster = setup(cfg_file, fam, name, seed, trace, full)
     t_built = time.perf_counter()
     n_prog = driver.warm(cluster, max(len(r.prompt) for r in reqs),
                          max(r.out_len for r in reqs)) if full else 0
@@ -185,7 +193,7 @@ def report_window(res) -> None:
 
 
 def cell_run(args) -> int:
-    from bench.lib import model, roofline, spec
+    from bench.lib import roofline, spec
     from bench.lib.record import Run
     bench = spec.benchmark()
     w = spec.workload(bench, args.workload)
@@ -193,18 +201,19 @@ def cell_run(args) -> int:
     from bench.lib import driver
     driver.enable_cache()
     cfg_file = spec.config_file(bench, w["config"])
+    fam = spec.family(cfg_file)
     traffic = spec.traffic_file(w["traffic"])
     cell = spec.cell_file(w["name"])
     dev = devs[0]
     pk = roofline.peaks(dev.device_kind)
     trace = bool(args.trace)
-    res = serve(cfg_file, traffic, cell["rate_per_s"], args.seconds,
+    res = serve(cfg_file, fam, traffic, cell["rate_per_s"], args.seconds,
                 args.seed, w["config"], traffic["drain_s"], trace)
     report_window(res)
     run = Run(outs=res["outs"], t0=res["t0"], t_end=res["t_end"],
               t_drain=res["t_drain"], setup_s=res["t0"] - T_START,
               counters=res["counters"], plan=res["plan"], trace=res["trace"],
-              dims=res["dims"], peaks=pk, calls=res["calls"],
+              family=fam, dims=res["dims"], peaks=pk, calls=res["calls"],
               t_trace=res["t_trace"])
     section = "per_layer" if trace else "end_to_end"
     metrics = {}
@@ -221,11 +230,11 @@ def cell_run(args) -> int:
     compiles = res["compiles"].backend_compiles()
     del res, run
     driver.free_device()
-    dims = model.dims(cfg_file)
+    dims = fam.dims(cfg_file)
     limits = cell["check"]
     t = time.perf_counter()
-    got = check(outs, prompts, dims, args.seed, limits,
-                model.make_weights(dims, args.seed))
+    got = check(outs, prompts, fam, dims, args.seed, limits,
+                fam.make_weights(dims, args.seed))
     got["compiles_in_window"] = compiles
     log(f"reference: {got['sampled_requests']} requests, "
         f"{got['sampled_tokens']} served tokens compared in "
@@ -252,19 +261,20 @@ def tool_run(args) -> int:
     """--zero-load, --sweep, --readings: one process, one set-up."""
     import numpy as np
 
-    from bench.lib import driver, judge, model, spec, traffic as gen
+    from bench.lib import driver, judge, spec, traffic as gen
     bench = spec.benchmark()
     w = spec.workload(bench, args.workload)
     chips(w["chips"])
     driver.enable_cache()
     cfg_file = spec.config_file(bench, w["config"])
+    fam = spec.family(cfg_file)
     traffic = spec.traffic_file(w["traffic"])
-    dims = model.dims(cfg_file)
+    dims = fam.dims(cfg_file)
     out = {"workload": w["name"], "mode": None, "rows": []}
     if args.zero_load:
         out["mode"] = "zero_load"
         lens = [int(x) for x in args.zero_load.split(",")]
-        _, cluster = setup(cfg_file, w["config"], args.seed, False)
+        _, cluster = setup(cfg_file, fam, w["config"], args.seed, False)
         driver.warm(cluster, max(lens), 2)
         driver.warm_request(cluster)
         rng = np.random.default_rng(args.seed)
@@ -290,7 +300,7 @@ def tool_run(args) -> int:
         reqs_all = {(s, r): gen.generate(traffic, r, args.seconds, s,
                                          dims.vocab)
                     for s in seeds for r in rates}
-        _, cluster = setup(cfg_file, w["config"], args.seed, False)
+        _, cluster = setup(cfg_file, fam, w["config"], args.seed, False)
         driver.warm(cluster,
                     max(len(q.prompt) for v in reqs_all.values() for q in v),
                     max(q.out_len for v in reqs_all.values() for q in v))
@@ -332,7 +342,7 @@ def tool_run(args) -> int:
         reqs_all = {s: gen.generate(traffic, cell["rate_per_s"],
                                     args.seconds, s, dims.vocab)
                     for s in seeds}
-        _, cluster = setup(cfg_file, w["config"], seeds[0], False)
+        _, cluster = setup(cfg_file, fam, w["config"], seeds[0], False)
         driver.warm(cluster,
                     max(len(q.prompt) for v in reqs_all.values() for q in v),
                     max(q.out_len for v in reqs_all.values() for q in v))
@@ -341,8 +351,8 @@ def tool_run(args) -> int:
         for seed in seeds:
             # the same programs with the seed's weights in place; no
             # prefix can hit across seeds: every prompt is random tokens
-            driver.swap_weights(cluster, lambda: model.make_weights(
-                dims, seed, model.dtype_of(cfg_file)))
+            driver.swap_weights(cluster, lambda: fam.make_weights(
+                dims, seed, dtype_of(cfg_file)))
             reqs = reqs_all[seed]
             counter.reset()
             counter.armed = True
@@ -351,8 +361,8 @@ def tool_run(args) -> int:
             counter.armed = False
             driver.clear(cluster, res["outs"])
             params = cluster.drivers[0].engine.params
-            got = check(res["outs"], {r.rid: r.prompt for r in reqs}, dims,
-                        seed, cell["check"], params, control=True)
+            got = check(res["outs"], {r.rid: r.prompt for r in reqs}, fam,
+                        dims, seed, cell["check"], params, control=True)
             got["compiles_in_window"] = counter.backend_compiles()
             ok, checks = verdict(got, cell["check"])
             c_ok, c_checks = verdict(dict(got, max_logit_gap=got[

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 import pytest
 
 from bench import run
+from bench.lib import spec
 
-TINY = {"source": "tiny", "qk_norm": True,
+TINY = {"source": "tiny", "family": "dense_gqa", "qk_norm": True,
         "zero_load_ttft_s": {"a": 0.05, "b": 1e-4},
         "config": {"model_type": "qwen3", "hidden_size": 128,
                    "intermediate_size": 256, "num_hidden_layers": 2,
@@ -25,6 +26,7 @@ TRAFFIC = {"max_context": 120, "drain_s": 30, "classes": [
      "output": {"mean": 6, "std": 3}, "ttft_slowdown": 3, "tpot_s": 0.05}]}
 LIMITS = {"max_logit_gap": 1e-3, "sample_requests": 4}
 SEED = 2 ** 31 + 77
+FAM = spec.family(TINY)
 
 
 def altered_token(cluster):
@@ -62,11 +64,11 @@ def first_token_altered(cluster):
 
 
 def served(fault, control=False):
-    res = run.serve(TINY, TRAFFIC, 2.0, 3.0, SEED, "tiny", 30, False,
+    res = run.serve(TINY, FAM, TRAFFIC, 2.0, 3.0, SEED, "tiny", 30, False,
                     full=False, on_cluster=fault)
     w = res["cluster"].drivers[0].engine.params
     got = run.check(res["outs"], {r.rid: r.prompt for r in res["reqs"]},
-                    res["dims"], SEED, LIMITS, w, control=control)
+                    FAM, res["dims"], SEED, LIMITS, w, control=control)
     return got
 
 
@@ -103,21 +105,21 @@ def test_readings_reuse_one_cluster_across_seeds():
     reference.  (At the program's CPU sizes nothing is warmed, so
     programs compile in the window here; on the chip the mode reports
     them.)"""
-    from bench.lib import driver, model, traffic as gen
-    res = run.serve(TINY, TRAFFIC, 2.0, 3.0, SEED, "tiny", 0, False,
+    from bench.lib import driver, traffic as gen
+    res = run.serve(TINY, FAM, TRAFFIC, 2.0, 3.0, SEED, "tiny", 0, False,
                     full=False)
     cluster, dims = res["cluster"], res["dims"]
     driver.clear(cluster, res["outs"])
     assert cluster.idle
     seed = SEED + 1
-    driver.swap_weights(cluster, lambda: model.make_weights(dims, seed))
+    driver.swap_weights(cluster, lambda: FAM.make_weights(dims, seed))
     reqs = gen.generate(TRAFFIC, 2.0, 3.0, seed, dims.vocab)
     again = driver.run_window(cluster, reqs, 3.0, 30, run.ttft_fit(TINY))
-    got = run.check(again["outs"], {r.rid: r.prompt for r in reqs}, dims,
-                    seed, LIMITS, model.make_weights(dims, seed))
+    got = run.check(again["outs"], {r.rid: r.prompt for r in reqs}, FAM,
+                    dims, seed, LIMITS, FAM.make_weights(dims, seed))
     ok, checks = run.verdict(got, LIMITS)
     assert ok and got["sampled_tokens"] >= 20, checks
     # the old seed's weights would not pass
     stale = run.check(again["outs"], {r.rid: r.prompt for r in reqs},
-                      dims, seed, LIMITS, model.make_weights(dims, SEED))
+                      FAM, dims, seed, LIMITS, FAM.make_weights(dims, SEED))
     assert not run.verdict(stale, LIMITS)[0]

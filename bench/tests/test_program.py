@@ -94,7 +94,7 @@ def run_of(t0_ms, t_end_ms, outs=(), decode_tokens=0):
     return Run(outs=list(outs), t0=t0_ms / 1e3, t_end=t_end_ms / 1e3,
                t_drain=t_end_ms / 1e3 + 100.0, setup_s=1.0,
                counters={"decode_tokens": decode_tokens}, plan=None,
-               trace=None, dims=None, peaks={})
+               trace=None, family=None, dims=None, peaks={})
 
 
 def read_all(run):
@@ -143,15 +143,15 @@ def test_readers_on_a_traced_run_through_the_cluster():
     profiler over the window, then the readers as ``cell_run`` calls
     them."""
     from bench import run as bench_run
-    from bench.tests.test_harness import SEED, TINY, TRAFFIC
+    from bench.tests.test_harness import FAM, SEED, TINY, TRAFFIC
     gc.collect()            # the tracers of earlier tests' clusters
     assert exporters.live_tracers() == []
-    res = bench_run.serve(TINY, TRAFFIC, 2.0, 3.0, SEED, "tiny", 0, True,
-                          full=False)
+    res = bench_run.serve(TINY, FAM, TRAFFIC, 2.0, 3.0, SEED, "tiny", 0,
+                          True, full=False)
     run = Run(outs=res["outs"], t0=res["t0"], t_end=res["t_end"],
               t_drain=res["t_drain"], setup_s=1.0,
               counters=res["counters"], plan=res["plan"],
-              trace=res["trace"], dims=res["dims"], peaks={},
+              trace=res["trace"], family=FAM, dims=res["dims"], peaks={},
               calls=res["calls"], t_trace=res["t_trace"])
     got = read_all(run)
     assert 0 < got["decode_lane_steps_useful_pct"] <= 100
