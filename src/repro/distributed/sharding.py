@@ -225,7 +225,7 @@ class ServingShardPlan:
     size: int                  # number of shards along that axis
     heads: bool                # GQA q/kv heads shard (H % n == KVH % n == 0)
     mla_heads: bool            # MLA q heads shard (latent pools replicate)
-    experts: bool              # MoE experts shard (E % n == 0)
+    experts: bool              # MoE held experts shard (held % n == 0)
     mlp: bool                  # dense-FFN hidden dim shards (d_ff % n == 0)
     ssm_lanes: bool            # SSM state lane-shards at rest
 
@@ -244,7 +244,7 @@ def serving_shard_plan(cfg: ModelConfig, mesh: Mesh, axis: str = "model",
              and cfg.n_heads % n == 0 and cfg.n_kv_heads % n == 0)
     mla_heads = n > 1 and has_mla and cfg.n_heads % n == 0
     experts = (n > 1 and cfg.moe is not None
-               and cfg.moe.n_experts % n == 0)
+               and cfg.moe.n_held % n == 0)
     mlp = n > 1 and cfg.d_ff > 0 and cfg.d_ff % n == 0
     ssm_lanes = (n > 1 and cfg.ssm is not None and "ssm" in kinds
                  and max_seqs > 0 and max_seqs % n == 0)
@@ -282,7 +282,7 @@ def serving_param_specs(params, cfg: ModelConfig, plan: ServingShardPlan):
         if gate and not mla and re.search(r"attn/(bq|bk|bv)$", path):
             return P(ax, None)
         if plan.experts and re.search(r"moe/(w_gate|w_up|w_down)$", path):
-            return P(ax, None, None)         # (E, ...) expert-parallel
+            return P(ax, None, None)         # (held, ...) expert-parallel
         if plan.mlp and re.search(r"mlp/(w_gate|w_up)$", path):
             return P(None, ax)
         if plan.mlp and tail == "b_up" and "mlp/" in path:

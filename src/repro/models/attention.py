@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope, rms_head_norm
+from repro.models.layers import (apply_rope, rms_head_norm,
+                                 rope_softmax_scale)
 
 NEG_INF = -1e30
 
@@ -472,11 +473,15 @@ def mla_forward(p, x, cfg: ModelConfig, *, positions, cache=None, pos0=None,
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     # compressed kv
     ckv = rms_head_norm(p["kv_norm"], x @ p["w_dkv"])          # (B,Sq,r)
-    krope = apply_rope((x @ p["w_krope"])[:, :, None, :],
-                       positions, cfg.rope_theta)[:, :, 0, :]  # (B,Sq,rope_hd)
+    krope = apply_rope((x @ p["w_krope"])[:, :, None, :], positions,
+                       cfg.rope_theta,
+                       cfg.rope_scaling)[:, :, 0, :]           # (B,Sq,rope_hd)
+    # one softmax scale for the fused prefill, absorbed and naive paths
+    scale = ((nope + rope_hd) ** -0.5
+             * rope_softmax_scale(cfg.rope_scaling))
 
     if cache is not None and "ckv_pages" in cache:
         if chunk_len is None:
@@ -491,7 +496,7 @@ def mla_forward(p, x, cfg: ModelConfig, *, positions, cache=None, pos0=None,
             ctx_lat, cc, ck = ops.mla_paged_prefill(
                 q_lat, q_rope, ckv, krope, cache["ckv_pages"],
                 cache["krope_pages"], block_tables, pos0, chunk_len, layer,
-                scale=(nope + rope_hd) ** -0.5)
+                scale=scale)
             ctx = jnp.einsum("bqhr,rhv->bqhv", ctx_lat.astype(x.dtype),
                              p["w_uv"])
             if shard is not None and shard.mla_heads:
@@ -529,7 +534,6 @@ def mla_forward(p, x, cfg: ModelConfig, *, positions, cache=None, pos0=None,
         q_pos0 = jnp.zeros((B,), jnp.int32)
 
     Sk = ckv_all.shape[1]
-    scale = (nope + rope_hd) ** -0.5
     if cfg.mla_absorb and Sq <= 4:
         # Absorbed-matmul decode (DeepSeek-V2 §2.1.3 / §Perf iteration 2):
         # fold w_uk into the query and w_uv into the output so attention

@@ -27,12 +27,45 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                    # the router's width
     top_k: int
     d_ff_expert: int
     n_shared: int = 0                 # always-on shared experts (DeepSeek)
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01   # aux loss weight (training)
+    # The experts held here: the first ``n_held`` (0 = all ``n_experts``).
+    # The router still spans every expert; the layer computes only the
+    # held experts' part of the result (one rank of an expert-parallel
+    # deployment, models/moe.py).
+    n_held: int = 0
+    # top-k weights renormalised to sum to 1, else the softmax scores as
+    # they are (DeepSeek-V2)
+    norm_topk_prob: bool = True
+
+    def __post_init__(self):
+        if self.n_held == 0:
+            object.__setattr__(self, "n_held", self.n_experts)
+        assert 0 < self.n_held <= self.n_experts, (
+            f"{self.n_held} experts held, outside the router's "
+            f"{self.n_experts}")
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (Peng et al. 2023, arXiv:2309.00071) as
+    DeepSeek-V2 configures it: the rotary inverse frequencies blend
+    interpolation (divided by ``factor``) and extrapolation along a
+    linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations, and the attention softmax scale is multiplied
+    by mscale(factor, mscale_all_dim)**2.  (The published code also
+    scales cos/sin by mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim), which is 1 where the two are equal, as in every
+    DeepSeek-V2 config; only that case is modelled.)"""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,6 +108,7 @@ class ModelConfig:
     head_dim: int = 0                 # 0 = d_model // n_heads
     block_pattern: tuple = ()         # () = ("attn",) * n_layers
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None
     qk_norm: bool = False
     use_bias: bool = False
     act: str = "swiglu"               # swiglu | gelu
@@ -165,7 +199,7 @@ class ModelConfig:
             return 2 * attn + ffn
         if kind == "attn_moe":
             m = self.moe
-            moe_ffn = (m.n_experts + m.n_shared) * ffn_mult * d * m.d_ff_expert
+            moe_ffn = (m.n_held + m.n_shared) * ffn_mult * d * m.d_ff_expert
             return attn + moe_ffn + d * m.n_experts
         if kind in ("mla", "mla_moe"):
             c = self.mla
@@ -178,7 +212,7 @@ class ModelConfig:
             if kind == "mla":
                 return mla + ffn_mult * d * f
             m = self.moe
-            moe_ffn = (m.n_experts + m.n_shared) * ffn_mult * d * m.d_ff_expert
+            moe_ffn = (m.n_held + m.n_shared) * ffn_mult * d * m.d_ff_expert
             return mla + moe_ffn + d * m.n_experts
         if kind == "ssm":
             s = self.ssm
@@ -191,7 +225,8 @@ class ModelConfig:
         raise ValueError(kind)
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top-k + shared only)."""
+        """Parameters touched per token (MoE: at most top-k of the held
+        experts, and the shared ones)."""
         if self.moe is None:
             return self.param_count()
         m = self.moe
@@ -200,7 +235,7 @@ class ModelConfig:
         per_expert = ffn_mult * self.d_model * m.d_ff_expert
         n_moe_layers = sum(1 for k in self.block_pattern
                            if k in ("attn_moe", "mla_moe"))
-        inactive = n_moe_layers * (m.n_experts - m.top_k) * per_expert
+        inactive = n_moe_layers * max(m.n_held - m.top_k, 0) * per_expert
         return full - inactive
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:

@@ -1,6 +1,8 @@
 """Shared neural-net layers: norms, RoPE, MLPs, embeddings (pure JAX)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -32,15 +34,57 @@ def rms_head_norm(scale, x, eps: float = 1e-6):
 
 
 # ------------------------------- RoPE ---------------------------------- #
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                       dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1
+    (1 for factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (B, S, H, hd); positions: (B, S) int32."""
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         max_pos: int) -> float:
+    """The rotary dim whose wavelength fits ``rotations`` times into the
+    original context."""
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    """Rotary inverse frequencies (head_dim/2,).  With YaRN ``scaling``
+    (config.YarnScaling) the dims below the ``beta_fast`` correction dim
+    keep their frequency, those above the ``beta_slow`` one are divided
+    by ``factor``, and a linear ramp blends the two in between; this
+    holds at every position."""
+    extra = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                        dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return extra
+    s = scaling
+    low = max(math.floor(_yarn_correction_dim(
+        s.beta_fast, head_dim, theta, s.original_max_position_embeddings)),
+        0)
+    high = min(math.ceil(_yarn_correction_dim(
+        s.beta_slow, head_dim, theta, s.original_max_position_embeddings)),
+        head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / s.factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_softmax_scale(scaling) -> float:
+    """Factor on the attention softmax scale: mscale(factor,
+    mscale_all_dim)**2 under YaRN with ``mscale_all_dim`` set, else 1."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def apply_rope(x, positions, theta: float = 10000.0, scaling=None):
+    """x: (B, S, H, hd); positions: (B, S) int32.  Rotate-half layout;
+    ``scaling``: YaRN (config.YarnScaling) or None."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    freqs = rope_freqs(hd, theta, scaling)              # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,hd/2)
     cos = jnp.cos(ang)[..., None, :]                    # (B,S,1,hd/2)
     sin = jnp.sin(ang)[..., None, :]

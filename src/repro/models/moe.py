@@ -9,8 +9,18 @@ parallelism (experts axis on the "model" mesh axis), and has fully static
 shapes.  Tokens overflowing an expert's capacity are dropped (standard
 Switch behaviour) — the load-balance aux loss keeps this rare.
 
-``mode="dense"`` computes every expert on every token (exact, no drops) —
-used as the small-shape reference oracle in tests.
+The layer holds a share of the experts, the first ``MoEConfig.n_held``
+(all of them by default): the router spans all E experts, and only the
+units routed to held experts are scattered into the (held, C, D) buffer
+and computed.  The result is that share's part of the layer plus the
+shared experts, which is what one rank of an expert-parallel deployment
+passes on.  Under the engine's ``shard_map`` the held experts split
+evenly over the shards (shard i holds n_held/n experts from
+i * n_held/n) and the shards' parts are ``psum``'d — the same code, with
+the exchange.
+
+``mode="dense"`` computes every held expert on every token (exact, no
+drops) — used as the small-shape reference oracle in tests.
 """
 from __future__ import annotations
 
@@ -27,11 +37,12 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
     d, f = cfg.d_model, m.d_ff_expert
     ks = jax.random.split(key, 7)
     s_in, s_out = d ** -0.5, f ** -0.5
+    E = m.n_held                       # experts held here
     p = {
         "router": jax.random.normal(ks[0], (d, m.n_experts), jnp.float32) * s_in,
-        "w_gate": jax.random.normal(ks[1], (m.n_experts, d, f), dtype) * s_in,
-        "w_up": jax.random.normal(ks[2], (m.n_experts, d, f), dtype) * s_in,
-        "w_down": jax.random.normal(ks[3], (m.n_experts, f, d), dtype) * s_out,
+        "w_gate": jax.random.normal(ks[1], (E, d, f), dtype) * s_in,
+        "w_up": jax.random.normal(ks[2], (E, d, f), dtype) * s_in,
+        "w_down": jax.random.normal(ks[3], (E, f, d), dtype) * s_out,
     }
     if m.n_shared:
         p["shared_gate"] = jax.random.normal(
@@ -43,11 +54,31 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
+def capacity(cfg: ModelConfig, tokens: int, capacity_factor=None) -> int:
+    """Rows per expert buffer for a call over ``tokens`` tokens:
+    ceil(cf * k * T / E) over the router's E (cf None -> 2.0).  Any
+    cf >= E/k gives C >= T: dispatch is dropless."""
+    m = cfg.moe
+    cf = 2.0 if capacity_factor is None else capacity_factor
+    return max(1, math.ceil(cf * m.top_k * tokens / m.n_experts))
+
+
+def held_range(m, shard=None):
+    """(first expert held, number held) by this layer; inside the
+    engine's expert-parallel ``shard_map`` each shard holds an even slice
+    of the held range."""
+    if shard is not None and shard.experts:
+        n = m.n_held // shard.size
+        return jax.lax.axis_index(shard.axis) * n, n
+    return 0, m.n_held
+
+
 def _route(p, xt, m):
     logits = xt.astype(jnp.float32) @ p["router"]            # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     topv, topi = jax.lax.top_k(probs, m.top_k)               # (T, k)
-    topv = topv / jnp.clip(jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        topv = topv / jnp.clip(jnp.sum(topv, axis=-1, keepdims=True), 1e-9)
     return probs, topv, topi
 
 
@@ -59,8 +90,11 @@ def _expert_ffn(p, h_in):
 
 
 def moe_forward(p, x, cfg: ModelConfig, mode: str = "dispatch",
-                capacity_factor=None, shard=None):
-    """Returns (out, aux) where aux carries load-balance terms.
+                capacity_factor=None, shard=None, live=None):
+    """Returns (out, aux) where aux carries load-balance terms and
+    ``units_held``: the (token, expert) units routed to (and kept by)
+    the held experts, over the tokens ``live`` (B, S) marks (all when
+    None).
 
     capacity_factor None -> 2.0 (training/dry-run default).  Any value
     >= n_experts/top_k makes dispatch provably dropless (C >= T), the
@@ -68,32 +102,34 @@ def moe_forward(p, x, cfg: ModelConfig, mode: str = "dispatch",
 
     shard: serving ShardPlan inside shard_map (expert parallel).  The
     router is replicated so routing/keep decisions are globally exact;
-    each shard scatters only the units routed to ITS expert slice
-    (remote units scatter nothing via an out-of-bounds index + drop),
-    runs ``_expert_ffn`` over the local (E/n, C, D) buffer, and the
-    per-unit outputs are ``psum``'d — exactly one shard contributes a
-    non-zero value per unit, so with top-k <= 2 the combined sum is
-    bit-identical to the single-device scatter-add."""
+    each shard scatters only the units routed to ITS slice of the held
+    experts (others scatter nothing via an out-of-bounds index + drop),
+    and the per-token outputs are ``psum``'d — exactly one shard
+    contributes a non-zero value per unit, so with top-k <= 2 the combined
+    sum is bit-identical to the single-device scatter-add."""
     m = cfg.moe
-    if capacity_factor is None:
-        capacity_factor = 2.0
     B, S, D = x.shape
     xt = x.reshape(-1, D)
     T = xt.shape[0]
     probs, topv, topi = _route(p, xt, m)
+    live_t = (jnp.ones((T,), bool) if live is None
+              else live.reshape(-1).astype(bool))
 
     if mode == "dense":
+        n = m.n_held
         combine = jnp.zeros_like(probs)
         combine = jax.vmap(lambda c, i, v: c.at[i].set(v))(combine, topi, topv)
-        combine = combine.astype(x.dtype)
+        combine = combine[:, :n].astype(x.dtype)
         h_g = jnp.einsum("td,edf->etf", xt, p["w_gate"])
         h_u = jnp.einsum("td,edf->etf", xt, p["w_up"])
         h = jax.nn.silu(h_g) * h_u
         eo = jnp.einsum("etf,efd->etd", h, p["w_down"])
         out = jnp.einsum("etd,te->td", eo, combine)
+        in_held = topi < n
+        units_held = jnp.sum(in_held & live_t[:, None], dtype=jnp.int32)
     else:
         E, k = m.n_experts, m.top_k
-        C = max(1, math.ceil(capacity_factor * k * T / E))
+        C = capacity(cfg, T, capacity_factor)
         e_u = topi.reshape(-1)                               # (T*k,)
         w_u = topv.reshape(-1).astype(x.dtype)
         t_u = jnp.repeat(jnp.arange(T), k)
@@ -102,28 +138,21 @@ def moe_forward(p, x, cfg: ModelConfig, mode: str = "dispatch",
         pos_u = (jnp.cumsum(oh, axis=0) - oh)[jnp.arange(e_u.shape[0]), e_u]
         keep = pos_u < C
         pos_c = jnp.where(keep, pos_u, C - 1)
+        start, n = held_range(m, shard)
+        e_loc = e_u - start
+        held = keep & (e_loc >= 0) & (e_loc < n)
+        e_scat = jnp.where(held, e_loc, n)         # OOB index -> dropped
+        vals = xt[t_u] * held[:, None].astype(x.dtype)
+        buf = jnp.zeros((n, C, D), x.dtype).at[e_scat, pos_c].add(
+            vals, mode="drop")
+        eo = _expert_ffn(p, buf)                   # (held, C, D)
+        unit_out = (eo[jnp.clip(e_loc, 0, n - 1), pos_c]
+                    * (w_u * held.astype(x.dtype))[:, None])
+        out = jnp.zeros((T, D), x.dtype).at[t_u].add(unit_out, mode="drop")
+        units_held = jnp.sum(held & live_t[t_u], dtype=jnp.int32)
         if shard is not None and shard.experts:
-            El = E // shard.size                   # local expert slice
-            e_loc = e_u - jax.lax.axis_index(shard.axis) * El
-            local = keep & (e_loc >= 0) & (e_loc < El)
-            e_scat = jnp.where(local, e_loc, El)   # OOB index -> dropped
-            vals = xt[t_u] * local[:, None].astype(x.dtype)
-            buf = jnp.zeros((El, C, D), x.dtype).at[e_scat, pos_c].add(
-                vals, mode="drop")
-            eo = _expert_ffn(p, buf)               # (El, C, D)
-            unit_out = (eo[jnp.clip(e_loc, 0, El - 1), pos_c]
-                        * (w_u * local.astype(x.dtype))[:, None])
-            part = jnp.zeros((T, D), x.dtype).at[t_u].add(
-                unit_out, mode="drop")
-            out = jax.lax.psum(part, shard.axis)
-        else:
-            vals = xt[t_u] * keep[:, None].astype(x.dtype)
-            buf = jnp.zeros((E, C, D), x.dtype).at[e_u, pos_c].add(
-                vals, mode="drop")
-            eo = _expert_ffn(p, buf)                         # (E, C, D)
-            unit_out = eo[e_u, pos_c] * (w_u * keep.astype(x.dtype))[:, None]
-            out = jnp.zeros((T, D), x.dtype).at[t_u].add(
-                unit_out, mode="drop")
+            out = jax.lax.psum(out, shard.axis)
+            units_held = jax.lax.psum(units_held, shard.axis)
 
     if m.n_shared:
         sh = jax.nn.silu(xt @ p["shared_gate"]) * (xt @ p["shared_up"])
@@ -134,4 +163,5 @@ def moe_forward(p, x, cfg: ModelConfig, mode: str = "dispatch",
     router_mean = jnp.mean(probs, axis=0)
     aux_loss = m.n_experts * jnp.sum(density / m.top_k * router_mean)
     return out.reshape(B, S, D), {"aux_loss": aux_loss,
-                                  "expert_density": density}
+                                  "expert_density": density,
+                                  "units_held": units_held}

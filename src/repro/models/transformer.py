@@ -10,8 +10,11 @@ Entry point semantics:
   * ``model_forward(..., cache=None)``          — full causal (training).
   * ``model_forward(..., cache, pos0)``         — chunked prefill / decode:
     the S new tokens are written into each layer cache at offset pos0 (B,).
-Returns hidden states; ``logits`` / ``loss`` heads live in losses.py and
-the serving/training layers.
+Returns hidden states, the new caches and the step's auxiliary terms
+(``aux_loss``, the MoE load-balance loss, and ``moe_units_held``, the
+live (token, expert) units routed to held experts, summed over layers);
+``logits`` / ``loss`` heads live in losses.py and the serving/training
+layers.
 """
 from __future__ import annotations
 
@@ -32,6 +35,15 @@ from repro.models.ssm import init_ssm, ssm_decode_step, ssm_forward
 ATTN_KINDS = ("attn", "attn_moe", "cross_attn", "shared_attn")
 MLA_KINDS = ("mla", "mla_moe")
 MOE_KINDS = ("attn_moe", "mla_moe")
+
+
+def _no_aux():
+    return {"aux_loss": jnp.zeros((), jnp.float32),
+            "moe_units_held": jnp.zeros((), jnp.int32)}
+
+
+def _add_aux(a, b):
+    return jax.tree.map(jnp.add, a, b)
 
 
 # ------------------------------ blocks --------------------------------- #
@@ -61,7 +73,8 @@ def block_forward(p, kind: str, cfg: ModelConfig, x, *, positions,
                   cache=None, pos0=None, enc_kv=None, moe_cf=None,
                   block_tables=None, chunk_len=None, verify=False,
                   shard=None, layer=None):
-    """Returns (x, new_cache, aux_loss).
+    """Returns (x, new_cache, aux): aux as ``model_forward`` gives it,
+    for this layer.
 
     shard: serving ShardPlan when executing inside the engine's
     shard_map (distributed/sharding.py) — attention heads / MoE experts
@@ -71,7 +84,7 @@ def block_forward(p, kind: str, cfg: ModelConfig, x, *, positions,
     pools ride in the layer scan's carry); None = this layer's own cache."""
     if kind == "cross_attn":
         shard = None            # whole block replicates (serving specs)
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux()
     if kind == "ssm":
         h = apply_norm(p["norm1"], x, cfg.norm)
         if cache is not None and x.shape[1] == 1:
@@ -105,9 +118,13 @@ def block_forward(p, kind: str, cfg: ModelConfig, x, *, positions,
                                    gated=cfg.arch_type == "vlm")
     h2 = apply_norm(p["norm2"], x, cfg.norm)
     if kind in MOE_KINDS:
+        live = (None if chunk_len is None else
+                jnp.arange(x.shape[1])[None, :] < chunk_len[:, None])
         y2, moe_aux = moe_forward(p["moe"], h2, cfg,
-                                  capacity_factor=moe_cf, shard=shard)
-        aux = aux + moe_aux["aux_loss"]
+                                  capacity_factor=moe_cf, shard=shard,
+                                  live=live)
+        aux = {"aux_loss": moe_aux["aux_loss"],
+               "moe_units_held": moe_aux["units_held"]}
     else:
         y2 = apply_mlp(p["mlp"], h2, cfg.act, shard=shard)
     new_cache = {"self": new_self} if cache is not None else None
@@ -244,7 +261,10 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
                   cache=None, pos0=None, enc_states=None, moe_cf=None,
                   block_tables=None, chunk_len=None, verify=False,
                   shard=None):
-    """Returns (hidden (B,S,D), new_cache, aux_loss).
+    """Returns (hidden (B,S,D), new_cache, aux): aux holds ``aux_loss``
+    (MoE load balance, summed over layers) and ``moe_units_held`` (live
+    (token, expert) units routed to held experts, summed over layers; a
+    token is live below its lane's ``chunk_len``, every token without).
 
     block_tables: (B, max_pages) per-lane page tables when ``cache`` holds
     paged pools (init_paged_cache); chunk_len: (B,) true chunk lengths so
@@ -267,7 +287,7 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
                       jnp.clip(positions, 0, cfg.learned_pos - 1), axis=0)
         x = x + pe
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _no_aux()
     new_caches = [] if cache is not None else None
     segs = cfg.segments()
     for i, (kind, n) in enumerate(segs):
@@ -280,7 +300,7 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
                 cache=seg_c, pos0=pos0_arr, enc_kv=None, moe_cf=moe_cf,
                 block_tables=block_tables, chunk_len=chunk_len,
                 verify=verify, shard=shard)
-            aux_total += aux
+            aux_total = _add_aux(aux_total, aux)
             if cache is not None:
                 new_caches.append(c_new)
             continue
@@ -296,7 +316,7 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
                 pos0=pos0_arr, enc_kv=enc_kv, moe_cf=moe_cf,
                 block_tables=block_tables, chunk_len=chunk_len,
                 verify=verify, shard=shard)
-            aux_total += aux
+            aux_total = _add_aux(aux_total, aux)
             if cache is not None:
                 new_caches.append(c_new)
         elif _paged(seg_c):
@@ -318,7 +338,8 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
             (x, c_new, _), auxs = jax.lax.scan(
                 body, (x, seg_c, jnp.int32(0)), p)
             new_caches.append(c_new)
-            aux_total += jnp.sum(auxs)
+            aux_total = _add_aux(aux_total,
+                                 jax.tree.map(jnp.sum, auxs))
         else:
             def body(carry, layer):
                 xx = carry
@@ -341,7 +362,8 @@ def model_forward(params, cfg: ModelConfig, tokens_or_embeds, *,
                 new_caches.append(c_new)
             else:
                 x, (_, auxs) = jax.lax.scan(body, x, (p, None))
-            aux_total += jnp.sum(auxs)
+            aux_total = _add_aux(aux_total,
+                                 jax.tree.map(jnp.sum, auxs))
     x = apply_norm(params["final_norm"], x, cfg.norm)
     return x, new_caches, aux_total
 

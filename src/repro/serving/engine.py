@@ -25,7 +25,12 @@ Engine capacity is bounded by pages, not by max_slots × max_len slabs.
 ``counters`` tracks jitted device computations (prefill_calls,
 decode_calls, decode_tokens, decode_split_scans) so benchmarks/overhead.py
 can assert one device call per decode scan, and one scan per decode group
-whose budget fits a step bucket.
+whose budget fits a step bucket.  For an MoE model it also counts the
+expert layer's work: ``moe_expert_rows`` (rows the expert FFN computed:
+MoE layers x held experts x capacity, per step; the host knows it) and
+``moe_routed_units_held`` (live (token, expert) units routed to held
+experts, summed on the device: an MoE model's prefill and decode
+programs return it as one more output, read with the call's result).
 """
 from __future__ import annotations
 
@@ -41,7 +46,8 @@ import numpy as np
 from repro.core.batch import Batch
 from repro.core.slo import StageKind
 from repro.models.config import ModelConfig
-from repro.models.transformer import logits_fn, model_forward
+from repro.models.moe import capacity
+from repro.models.transformer import MOE_KINDS, logits_fn, model_forward
 from repro.serving.kvcache import PagedKVManager
 from repro.serving.sampling import sample
 
@@ -171,6 +177,7 @@ class ServingEngine:
         self.key = jax.random.PRNGKey(self.ecfg.seed)
         self._moe_cf = (float(cfg.moe.n_experts) / cfg.moe.top_k
                         if cfg.moe else None)
+        self._moe_layers = sum(k in MOE_KINDS for k in cfg.block_pattern)
         # Mesh-sharded serving: place params + at-rest pools per the
         # serving shard plan and wrap the three jitted programs in
         # shard_map.  The plan is read by model_forward (shard=...) at
@@ -230,7 +237,12 @@ class ServingEngine:
                          # speculation outcome totals feeding the per-class
                          # acceptance EWMA (core.spec_planner)
                          "spec_accepted_tokens": 0,
-                         "spec_drafted_tokens": 0}
+                         "spec_drafted_tokens": 0,
+                         # MoE: rows the expert FFN ran (held experts x
+                         # capacity per layer and step), and the live
+                         # (token, expert) units routed to held experts
+                         "moe_expert_rows": 0,
+                         "moe_routed_units_held": 0}
         # fresh request-level progress granted by the last admission's
         # prefix hit (hit tokens beyond preemption replay) — the driver
         # advances the request by this right after add/restore/readmit
@@ -275,12 +287,13 @@ class ServingEngine:
         cspec = shd.serving_cache_specs(self.kv.pools, self.cfg, plan,
                                         lane_view=True)
         rep = P()
+        moe = (rep,) if self._moe_layers else ()
         smap = functools.partial(jax.shard_map, mesh=self.mesh,
                                  check_vma=False)
         self._prefill = jax.jit(
             smap(self._prefill_forward,
                  in_specs=(pspec, rep, cspec, rep, rep, rep, rep, rep),
-                 out_specs=(rep, cspec)),
+                 out_specs=(rep, cspec) + moe),
             donate_argnums=(2,))
         self._verify = jax.jit(
             smap(self._verify_forward,
@@ -293,7 +306,7 @@ class ServingEngine:
             fn = smap(functools.partial(self._decode_scan, n_steps=n_steps),
                       in_specs=(pspec, cspec, rep, rep, rep, rep, rep,
                                 rep, rep),
-                      out_specs=(cspec, rep, rep, rep))
+                      out_specs=(cspec, rep, rep, rep) + moe)
             return fn(params, cache, tokens0, pos0, steps, eos, bt,
                       enc_states, key)
 
@@ -307,8 +320,9 @@ class ServingEngine:
         its last REAL position (true_len-1 of the padded chunk) — only that
         position's hidden state reaches the vocabulary projection, so the
         step never holds (lanes x chunk x vocab) logits.  Padded lanes
-        carry true_len 0: their writes drop and output is ignored."""
-        h, cache, _ = model_forward(params, self.cfg, tokens, cache=cache,
+        carry true_len 0: their writes drop and output is ignored.  An
+        MoE model also returns the live units routed to held experts."""
+        h, cache, aux = model_forward(params, self.cfg, tokens, cache=cache,
                                     pos0=pos0, enc_states=enc_states,
                                     moe_cf=self._moe_cf, block_tables=bt,
                                     chunk_len=true_len,
@@ -318,7 +332,7 @@ class ServingEngine:
         last = logits_fn(params, self.cfg, h_last)[:, 0]
         toks = jax.vmap(
             lambda lg, k: sample(lg, k, self.ecfg.temperature))(last, keys)
-        return toks, cache
+        return (toks, cache) + self._moe_out(aux["moe_units_held"])
 
     def _verify_forward(self, params, tokens, cache, pos0, true_len, bt,
                         enc_states):
@@ -337,13 +351,15 @@ class ServingEngine:
                      enc_states, key, *, n_steps):
         """All n_steps decode steps for a batch group in one device
         program.  Per-lane step budgets (``steps``) and EOS stop lanes
-        early; frozen lanes emit -1 and neither write KV nor advance."""
+        early; frozen lanes emit -1 and neither write KV nor advance.
+        Returns (cache, emitted (B, n_steps), pos, done), and for an MoE
+        model the live units routed to held experts over the scan."""
         lane_axes = self.kv.lane_select_axes()
 
         def step(carry, i):
-            cache, tok, pos, done, key = carry
+            cache, tok, pos, done, key, held = carry
             active = (~done) & (i < steps)
-            h, new_cache, _ = model_forward(
+            h, new_cache, aux = model_forward(
                 params, self.cfg, tok[:, None], cache=cache, pos0=pos,
                 enc_states=enc_states, moe_cf=self._moe_cf,
                 block_tables=bt, chunk_len=active.astype(jnp.int32),
@@ -364,13 +380,21 @@ class ServingEngine:
             tok = jnp.where(active, nxt, tok)
             pos = pos + active.astype(pos.dtype)
             done = done | (active & (nxt == eos))
-            return (cache, tok, pos, done, key), emit
+            held = held + aux["moe_units_held"]
+            return (cache, tok, pos, done, key, held), emit
 
         carry0 = (cache, tokens0, pos0,
-                  jnp.zeros(tokens0.shape, bool), key)
-        (cache, _, pos, done, _), emitted = jax.lax.scan(
+                  jnp.zeros(tokens0.shape, bool), key,
+                  jnp.zeros((), jnp.int32))
+        (cache, _, pos, done, _, held), emitted = jax.lax.scan(
             step, carry0, jnp.arange(n_steps))
-        return cache, emitted.T, pos, done                # emitted: (B, S)
+        # emitted: (B, S)
+        return (cache, emitted.T, pos, done) + self._moe_out(held)
+
+    def _moe_out(self, held) -> tuple:
+        """The extra output of an MoE model's programs: the device count
+        of live units routed to held experts (none for other models)."""
+        return (held,) if self._moe_layers else ()
 
     def _put(self, x):
         """A host value as a per-call device input, committed to this
@@ -654,7 +678,7 @@ class ServingEngine:
         from repro.models import attention as _attn
         with self._tspan("repro.engine.prefill.dispatch"):
             ops0 = dict(_attn.OP_STATS)
-            tok, cache = self._prefill(*args)
+            tok, cache, *held = self._prefill(*args)
             self.counters["prefill_scatter_ops"] += (
                 _attn.OP_STATS["paged_write"] - ops0["paged_write"])
             self.counters["prefill_attn_ops"] += (
@@ -664,7 +688,8 @@ class ServingEngine:
             self.counters["prefill_calls"] += 1
         with self._tspan("repro.engine.prefill.wait"):
             tok_h = np.asarray(tok)
-        with self._tspan("repro.engine.prefill.post"):
+            moe = self._count_moe(B * Lp, 1, held)
+        with self._tspan("repro.engine.prefill.post", **moe):
             self.kv.absorb(slots, cache)
             out: dict[int, list] = {}
             for i, (rid, chunk, _) in enumerate(recs):
@@ -779,7 +804,7 @@ class ServingEngine:
         # included, for every step — the work the device is handed
         with self._tspan("repro.engine.decode.dispatch",
                          lane_steps=n_steps * B):
-            cache, emitted, _, _ = self._decode(
+            cache, emitted, _, _, *held = self._decode(
                 self.params, cache, toks0, pos0, steps, eos, bt, enc, sk,
                 n_steps=n_steps)
             self.counters["decode_calls"] += 1
@@ -787,8 +812,9 @@ class ServingEngine:
             self.counters["decode_live_lanes"] += len(live)
         with self._tspan("repro.engine.decode.wait"):
             em = np.asarray(emitted)              # ONE host sync per scan
+            moe = self._count_moe(B, n_steps, held)
         out = {}
-        with self._tspan("repro.engine.decode.post"):
+        with self._tspan("repro.engine.decode.post", **moe):
             self.kv.absorb(slots, cache)
             for i, r in enumerate(live):
                 ctx = self.reqs[r]
@@ -803,6 +829,22 @@ class ServingEngine:
                 if ctx.eos is not None and toks and toks[-1] == ctx.eos:
                     ctx.done = True
         return out
+
+    def _count_moe(self, tokens: int, n_steps: int, held) -> dict:
+        """Count one call's expert work, for an MoE model ({} otherwise):
+        the rows the expert FFN ran (``tokens`` per step through every MoE
+        layer's held experts at their capacity, padding included) and the
+        call's device count of live units routed to held experts (its
+        program's extra output, ``held``: [count], read beside its
+        result).  Returned as attributes for the call's ``post`` span."""
+        if not held:
+            return {}
+        rows = (n_steps * self._moe_layers * self.cfg.moe.n_held
+                * capacity(self.cfg, tokens, self._moe_cf))
+        units = int(held[0])
+        self.counters["moe_expert_rows"] += rows
+        self.counters["moe_routed_units_held"] += units
+        return {"moe_expert_rows": rows, "moe_routed_units_held": units}
 
     def _gather_enc(self, rids, B):
         encs = [self.reqs[r].enc_states for r in rids]

@@ -235,7 +235,8 @@ class ClusterTelemetry:
                       "decode_lane_steps", "decode_live_lanes",
                       "decode_split_scans",
                       "preemptions", "prefix_hit_tokens",
-                      "spec_accepted_tokens", "spec_drafted_tokens")
+                      "spec_accepted_tokens", "spec_drafted_tokens",
+                      "moe_expert_rows", "moe_routed_units_held")
     _KV_EVENTS = ("cow_copies", "prefix_evictions", "partial_hit_tokens",
                   "partial_head_copies", "spilled_pages",
                   "prefetched_pages", "host_evictions",

@@ -16,8 +16,9 @@ from repro.training.optimizer import (AdamWConfig, adamw_update,
 
 def lm_loss(params, cfg: ModelConfig, tokens, labels, *, enc_states=None,
             moe_cf=None, aux_coef: float = 0.01):
-    h, _, aux = model_forward(params, cfg, tokens, enc_states=enc_states,
-                              moe_cf=moe_cf)
+    h, _, terms = model_forward(params, cfg, tokens, enc_states=enc_states,
+                                moe_cf=moe_cf)
+    aux = terms["aux_loss"]
     logits = logits_fn(params, cfg, h).astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]

@@ -102,6 +102,22 @@ def test_moe_expert_parallel_streams():
     _assert_parity(get_reduced("phi3.5-moe-42b-a6.6b"), (2, 4), ("experts",))
 
 
+def test_moe_held_share_expert_parallel_streams():
+    """A layer holding 4 of 8 routed experts (DeepSeek-V2 weighting)
+    splits its held experts over the shards: the same streams and
+    expert-work counters as on one device."""
+    base = get_reduced("phi3.5-moe-42b-a6.6b")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, n_experts=8, n_held=4, norm_topk_prob=False))
+    _assert_parity(cfg, (2, 4), ("experts",))
+    params = init_params(KEY, cfg)
+    prompts = _prompts(cfg)
+    _, one = _stream(cfg, params, None, prompts)
+    _, four = _stream(cfg, params, _mesh(4), prompts)
+    for k in ("moe_expert_rows", "moe_routed_units_held"):
+        assert four[k] == one[k] > 0, k
+
+
 def test_ssm_lane_sharded_streams():
     _assert_parity(get_reduced("mamba2-2.7b"), (2, 4), ("ssm_lanes",))
 

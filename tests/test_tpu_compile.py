@@ -13,11 +13,14 @@ stacks addressed at a layer index):
     with and without a sliding window;
   * the MLA latent-prefill kernel at DeepSeek-V2's kv_lora_rank 512 and
     rope head dim 64 (in the 128-lane pool the engine allocates), 16
-    query heads, at the engine's 256-token chunk bucket.
+    query heads, at the engine's 256-token chunk bucket, and at its
+    2048-token bucket over DeepSeek-V2-Lite's 14 layers with the
+    YaRN-scaled softmax scale.
 
 The topology is described inside a module-scoped fixture (never at
 import), and the persistent compilation cache is off around the compiles.
 """
+import math
 import os
 
 import jax
@@ -118,4 +121,20 @@ def test_mla_prefill_compiles(one_chip):
         one_chip, _f32(B, S, MLA_H, LATENT), _f32(B, S, MLA_H, ROPE),
         _f32(B, S, LATENT), _f32(B, S, ROPE),
         _f32(LAYERS, PAGES, PAGE, LATENT), _f32(LAYERS, PAGES, PAGE, ROPE_POOL),
+        _i32(B, TABLE), _i32(B), _i32(B), _i32())
+
+
+def test_mla_prefill_compiles_at_the_top_bucket(one_chip):
+    """DeepSeek-V2-Lite's prefill: the engine's 2048-token bucket over
+    its 14-layer latent pools, at the YaRN-scaled softmax scale."""
+    B, S, layers = 8, 2048, 14
+    scale = (128 + ROPE) ** -0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2
+    _compile(
+        lambda ql, qr, cn, rn, cp, rp, t, p0, n, layer:
+            mla_paged_prefill_attention(ql, qr, cn, rn, cp, rp, t, p0, n,
+                                        scale=scale, layer=layer),
+        one_chip, _f32(B, S, MLA_H, LATENT), _f32(B, S, MLA_H, ROPE),
+        _f32(B, S, LATENT), _f32(B, S, ROPE),
+        _f32(layers, PAGES, PAGE, LATENT),
+        _f32(layers, PAGES, PAGE, ROPE_POOL),
         _i32(B, TABLE), _i32(B), _i32(B), _i32())
